@@ -94,7 +94,7 @@ def conv2d_quantized(x: jnp.ndarray, filters: jnp.ndarray,
     if mode in (QuantMode.F32, QuantMode.BF16):
         y = jnp.dot(a, w2)
     else:
-        y = ops.quantized_matmul(a, w2, mode, backend, True)
+        y = ops.quantized_matmul(a, w2, mode, backend)
     return y.reshape(b, oh, ow, cout)
 
 

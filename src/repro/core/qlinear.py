@@ -83,7 +83,7 @@ class QuantLinear:
             y = jnp.dot(x2.astype(jnp.float32), w.astype(jnp.float32))
         else:
             y = ops.quantized_matmul(x2, w.astype(jnp.float32), self.mode,
-                                     self.backend, True)
+                                     self.backend)
         if self.use_bias:
             y = y + params["b"]
         return y.reshape(*lead, self.d_out).astype(x.dtype)

@@ -10,9 +10,22 @@ TPU mapping of the paper's blocked GeMM (Algorithm 2):
   ``encoding.py`` plus ``BlockSpec.index_map`` tiling — the Pallas
   pipeline's HBM->VMEM double buffering plays the role of the paper's
   L1/L2 cache blocking (k_blk/m_blk/n_blk);
-* the paper's k-step of 8 bytes per loop iteration becomes ``word_chunk``
-  uint32 words per inner step: the (bm, bn, word_chunk) broadcast is the
-  VPU analogue of the NEON register outer product.
+* the paper's register outer product becomes one (bm, bn) VPU update
+  per uint32 word: the word's A column broadcast along lanes against its
+  B row broadcast along sublanes.  Both tiles are transposed once per
+  grid step into VMEM scratch so that the word loop indexes sublanes
+  only (a dynamic lane offset that is not a multiple of 128 does not
+  compile for the chip); ``word_chunk`` words are unrolled per loop
+  iteration.
+
+Blocks obey the TPU (8, 128) rule: the k-word block is either the whole
+padded word extent or a multiple of 128 words, and row/column blocks are
+multiples of 8 (clamped to the sublane-aligned problem, so a decode-
+sized m never pads up to a 128-row block).
+
+``interpret=None`` (every kernel's default) resolves through
+:func:`resolve_interpret`: the Pallas interpreter on the CPU backend,
+compiled Mosaic kernels everywhere else.
 
 Fused epilogue
 --------------
@@ -33,15 +46,26 @@ back.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def ceil_to(x: int, m: int) -> int:
     return -(-x // m) * m
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one platform decision for Pallas interpret mode: an explicit
+    value wins (compile tests pass False on a CPU host); None runs the
+    interpreter only where Mosaic cannot compile, the CPU backend."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
 
 
 @dataclasses.dataclass(frozen=True, order=True)
@@ -51,8 +75,9 @@ class TileConfig:
     ``block_m/block_n/block_kw`` are the Pallas grid tile sizes of
     :func:`lowbit_matmul_call`; ``word_chunk`` is the number of uint32
     words consumed per inner k step (the VPU analogue of the paper's
-    8-byte NEON k-step).  The XLA scan kernels honour only
-    ``word_chunk``; the Pallas kernels honour all four.
+    8-byte NEON k-step) — the scan width of the XLA kernels and the
+    unroll factor of the Pallas inner loops.  The XLA scan kernels honour
+    only ``word_chunk``; the Pallas kernels honour all four.
     """
     block_m: int = 128
     block_n: int = 128
@@ -121,8 +146,7 @@ def lowbit_matmul_call(
     block_m: int,
     block_n: int,
     block_kw: int,
-    word_chunk: int,
-    interpret: bool,
+    interpret: bool | None,
     acc_dtype=jnp.int32,
 ):
     """Run ``kernel_body`` over a (m/bm, n/bn, kw/bkw) grid.
@@ -138,9 +162,16 @@ def lowbit_matmul_call(
     m, kw = a_operands[0].shape
     n = b_operands[0].shape[0]
 
-    # The inner loop consumes word_chunk words per step: the k block must
-    # be a chunk multiple or trailing words would be silently dropped.
-    block_kw = ceil_to(min(block_kw, max(word_chunk, kw)), word_chunk)
+    # (8, 128) block rule: row/column blocks are sublane multiples no
+    # larger than the (aligned) problem — an untuned decode-sized m never
+    # pads up to a 128-row block, and the paper's 24..96-wide layers keep
+    # a block of their own width (a block that spans the whole padded
+    # extent may take any width); the k-word block is a lane dimension
+    # of the operand tiles, so it is the whole word extent or a multiple
+    # of 128 words.
+    block_m = min(ceil_to(block_m, 8), ceil_to(m, 8))
+    block_n = min(ceil_to(block_n, 8), ceil_to(n, 8))
+    block_kw = kw if block_kw >= kw else min(ceil_to(block_kw, 128), kw)
 
     mp, np_, kwp = ceil_to(m, block_m), ceil_to(n, block_n), ceil_to(kw, block_kw)
     a_ops = [pad2d(a, mp, kwp) for a in a_operands]
@@ -175,7 +206,7 @@ def lowbit_matmul_call(
                   + [r_spec] * len(r_ops) + [c_spec] * len(c_ops)),
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((mp, np_), acc_dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*a_ops, *b_ops, *r_ops, *c_ops)
     return out[:m, :n]
 
@@ -187,24 +218,37 @@ def popcount_i32(x: jnp.ndarray) -> jnp.ndarray:
 def chunked_reduce(a_refs, b_refs, product_fn, *, word_chunk: int, acc_dtype):
     """The inner k loop of a low-bit microkernel.
 
-    Slices ``word_chunk`` uint32 words at a time out of the VMEM tiles,
-    forms the (bm, bn, wc) broadcast product via ``product_fn`` (which
-    returns the per-word signed contribution, already int32) and sums into
-    a (bm, bn) accumulator.
+    Transposes the (bm, bkw) / (bn, bkw) word tiles into (bkw, bm) /
+    (bkw, bn) VMEM scratch, then walks the words: word ``w``'s A column
+    (bm, 1) and B row (1, bn) broadcast into a (bm, bn) signed
+    contribution via ``product_fn`` (per-word popcount formula, already
+    int32) that sums into the accumulator.  ``word_chunk`` words are
+    unrolled per loop iteration (the largest divisor of the block's word
+    count not above it).
     """
     bm, bkw = a_refs[0].shape
     bn = b_refs[0].shape[0]
-    steps = bkw // word_chunk
+    wc = math.gcd(bkw, max(1, word_chunk))   # words per loop iteration
 
-    def body(i, acc):
-        s = i * word_chunk
-        a_sl = [r[:, pl.ds(s, word_chunk)][:, None, :] for r in a_refs]
-        b_sl = [r[:, pl.ds(s, word_chunk)][None, :, :] for r in b_refs]
-        contrib = product_fn(a_sl, b_sl)          # (bm, bn, wc) int32
-        return acc + jnp.sum(contrib, axis=-1).astype(acc_dtype)
+    def walk(*scratch):
+        at_s, bt_s = scratch[:len(a_refs)], scratch[len(a_refs):]
+        for src, dst in zip((*a_refs, *b_refs), scratch):
+            dst[...] = src[...].T
 
-    acc0 = jnp.zeros((bm, bn), acc_dtype)
-    return jax.lax.fori_loop(0, steps, body, acc0)
+        def body(i, acc):
+            for j in range(wc):                  # unrolled by hand: Mosaic
+                w = i * wc + j                   # takes no partial unroll
+                a_sl = [r[pl.ds(w, 1), :].reshape(bm, 1) for r in at_s]
+                b_sl = [r[pl.ds(w, 1), :] for r in bt_s]
+                acc = acc + product_fn(a_sl, b_sl).astype(acc_dtype)
+            return acc
+
+        return jax.lax.fori_loop(0, bkw // wc, body,
+                                 jnp.zeros((bm, bn), acc_dtype))
+
+    scratch = ([pltpu.VMEM((bkw, bm), jnp.uint32)] * len(a_refs)
+               + [pltpu.VMEM((bkw, bn), jnp.uint32)] * len(b_refs))
+    return pl.run_scoped(walk, *scratch)
 
 
 def scale_epilogue(acc_f32, r_refs, c_refs):

@@ -57,7 +57,7 @@ def bnn_matmul_pallas(
     block_n: int = _TILES.block_n,
     block_kw: int = _TILES.block_kw,
     word_chunk: int = _TILES.word_chunk,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
 
     def body(pid_k, num_k, a_refs, b_refs, r_refs, c_refs, o_ref):
@@ -76,7 +76,7 @@ def bnn_matmul_pallas(
     return lowbit_matmul_call(
         body, [a_bits], [b_bits_t],
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
     )
 
 
@@ -98,7 +98,7 @@ def bnn_matmul_fused_pallas(
     block_n: int = _TILES.block_n,
     block_kw: int = _TILES.block_kw,
     word_chunk: int = _TILES.word_chunk,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """eq. (6) + eq. (2) in one pass: float32 (m, n) output."""
 
@@ -121,6 +121,6 @@ def bnn_matmul_fused_pallas(
         body, [a_bits], [b_bits_t],
         row_operands=[row_scale], col_operands=cols,
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
         acc_dtype=jnp.float32,
     )

@@ -64,7 +64,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.kernels import registry
-from repro.kernels._matmul_common import ceil_to, pad2d, scale_epilogue
+from repro.kernels._matmul_common import (ceil_to, pad2d, resolve_interpret,
+                                          scale_epilogue)
 from repro.kernels.modes import QuantMode
 from repro.tune import cache as tune_cache
 from repro.tune.space import CONV_PALLAS_SPACE, XLA_SPACE
@@ -269,6 +270,21 @@ def conv_weight_planes(qt) -> Tuple[jnp.ndarray, ...]:
 # Shared A-operand load path of the Pallas conv kernels
 # ---------------------------------------------------------------------------
 
+def interpreted_only(interpret: bool | None, backend: str) -> bool:
+    """Interpret flag for the fused-im2col Pallas kernels, which run only
+    in the Pallas interpreter.  They take the whole activation tensor as
+    one block and gather patches with a 4-D gather that Mosaic does not
+    lower ("Only 2D gather is supported"), so a compiled launch raises
+    here — never a silent fallback — until they get a band-blocked A
+    operand (ROADMAP S8).  The ``xla`` conv cell compiles everywhere."""
+    if resolve_interpret(interpret):
+        return True
+    raise NotImplementedError(
+        f"the fused-im2col conv kernel of backend {backend!r} runs only in "
+        f"the Pallas interpreter (CPU); on {jax.default_backend()!r} use "
+        f"backend='xla' for qconv/conv2d_packed")
+
+
 def gather_patch_tile(xv: jnp.ndarray, pid_m, *, block_m: int, m: int,
                       oh: int, ow: int, stride: int, kh: int,
                       kw: int) -> jnp.ndarray:
@@ -369,7 +385,7 @@ def _conv_xla_fused(mode: QuantMode, x, b_planes, geometry, stride, padding,
 def _conv_pallas_fused(mode: QuantMode, x, b_planes, geometry, stride,
                        padding, stats, col_scale, bias, *, block_m: int,
                        block_n: int, block_kw: int, word_chunk: int,
-                       interpret: bool):
+                       interpret: bool | None):
     from repro.core import encoding
     from repro.kernels import ops
 
@@ -464,7 +480,7 @@ def _conv_pallas_fused(mode: QuantMode, x, b_planes, geometry, stride,
                   + [c_spec] * len(col_ops)),
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=interpret,
+        interpret=interpreted_only(interpret, "pallas"),
     )(xp, *b_ops, *stat_ops, *col_ops)
     return out[:m, :cout].reshape(bsz, oh, ow, cout)
 
@@ -491,7 +507,7 @@ def _register_conv_kernels():
 
     def make_pallas(mode):
         def fn(x, b_planes, geometry, stride, padding, stats, col_scale,
-               bias, *, interpret=True, tiles=None):
+               bias, *, interpret=None, tiles=None):
             t = _resolve_conv_tiles(mode, "pallas", x.shape, geometry,
                                     stride, padding, tiles)
             return _conv_pallas_fused(mode, x, b_planes, geometry, stride,
@@ -502,7 +518,7 @@ def _register_conv_kernels():
 
     def make_xla(mode):
         def fn(x, b_planes, geometry, stride, padding, stats, col_scale,
-               bias, *, interpret=True, tiles=None):
+               bias, *, interpret=None, tiles=None):
             del interpret
             t = _resolve_conv_tiles(mode, "xla", x.shape, geometry,
                                     stride, padding, tiles)

@@ -14,13 +14,14 @@ and the decode to ±1/0 bf16 tiles happens *in-register*, immediately
 ahead of the multiply —
 
 * **gemm** (``dense_matmul_fused_pallas``): the standard (m-blocks,
-  n-blocks, k-blocks) grid of ``lowbit_matmul_call``; per inner step a
-  ``word_chunk``-word slice of each operand's planes unpacks to a
-  (block, word_chunk*32) bf16 tile and feeds ``jnp.dot`` with float32
+  n-blocks, k-blocks) grid of ``lowbit_matmul_call``; per inner step one
+  bit plane of every word of each operand's (block, block_kw) tile
+  decodes to a ±1/0 bf16 tile and feeds ``jnp.dot`` with float32
   accumulation (exact: all products are ±1/0 integers and every partial
-  sum is < 2^24), with the eq. (2) scale/bias epilogue applied at
-  ``pid_k == num_k - 1`` — the unpacked operands and the accumulator
-  never touch HBM;
+  sum is < 2^24) — 32 dots of depth block_kw per k block, ``word_chunk``
+  (at most 32) of them unrolled per loop iteration — with the eq. (2)
+  scale/bias epilogue applied at ``pid_k == num_k - 1``: the unpacked
+  operands and the accumulator never touch HBM;
 * **im2col_fused** (``dense_conv_fused_pallas``): the fused conv layout
   — patch coordinates from ``program_id`` via the shared
   ``conv_fused.gather_patch_tile``, the raw activation tile quantized to
@@ -46,6 +47,7 @@ slices the unpacked weight words back to Cin per position.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +64,7 @@ from repro.kernels.conv_fused import (
     _resolve_conv_tiles,
     conv_spatial_pad,
     gather_patch_tile,
+    interpreted_only,
     quantize_patch_values,
 )
 from repro.kernels.modes import QuantMode
@@ -83,12 +86,22 @@ def _unpack_bits(words: jnp.ndarray) -> jnp.ndarray:
                         words.shape[-1] * 32).astype(jnp.int32)
 
 
-def _unpack_vals(planes, ternary: bool) -> jnp.ndarray:
-    """Bit-plane word slice(s) -> ±1/0 bf16 values, in-register."""
+def _bit_plane_vals(planes, bit, ternary: bool) -> jnp.ndarray:
+    """Bit ``bit`` of every word of the (rows, words) plane tile(s) ->
+    ±1/0 bf16 values, one per word.  Slicing the depth by bit plane
+    instead of by word keeps the word axis on lanes — no lane reshape —
+    and a dot over one bit plane of both operands sums the same products
+    as a dot over their unpacked words, in a different order (exact:
+    integer partial sums < 2^24)."""
+    sh = jnp.asarray(bit).astype(jnp.uint32)
+
+    def bits(p):
+        return ((p >> sh) & jnp.uint32(1)).astype(jnp.int32)
+
     if ternary:
-        vals = _unpack_bits(planes[0]) - _unpack_bits(planes[1])
+        vals = bits(planes[0]) - bits(planes[1])
     else:
-        vals = 1 - 2 * _unpack_bits(planes[0])
+        vals = 1 - 2 * bits(planes[0])
     return vals.astype(jnp.bfloat16)
 
 
@@ -112,9 +125,9 @@ def dense_matmul_fused_pallas(
     *,
     block_m: int = 128,
     block_n: int = 128,
-    block_kw: int = 32,
+    block_kw: int = 128,
     word_chunk: int = 8,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Packed planes -> in-VMEM unpack -> MXU dot -> eq. (2), one pass.
 
@@ -122,41 +135,37 @@ def dense_matmul_fused_pallas(
     so the result is bit-identical to the materializing dense oracle.
     """
     ternary_a, ternary_b = _TERNARY_A[mode], _TERNARY_B[mode]
-    # Clamp the block extents to the (sublane-aligned) problem, so an
-    # untuned cache-miss dispatch never pads a 72-row matrix up to a
-    # 128-row block and unpacks + multiplies the pad rows.  The n clamp
-    # deliberately goes below the 128-lane tile: the paper's Table III
-    # widths are 24..96, where a 128-lane B block would *5x* the unpack
-    # work; lane-aligned candidates for real-TPU runs still come from
-    # DENSE_SPACE (all 128-multiples).  Applied identically to every
-    # tuned candidate, so the bake-off ranking is unaffected.
-    block_m = min(block_m, ceil_to(a_planes[0].shape[0], 8))
-    block_n = min(block_n, ceil_to(b_planes[0].shape[0], 8))
 
     def body(pid_k, num_k, a_refs, b_refs, r_refs, c_refs, o_ref):
         @pl.when(pid_k == 0)
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        bkw = a_refs[0].shape[-1]          # clamped block_kw
+        a = [r[...] for r in a_refs]           # (bm, bkw) uint32
+        b = [r[...] for r in b_refs]           # (bn, bkw) uint32
+        bkw = a[0].shape[-1]
+        # Bit ``bit`` of word ``w`` is logical depth (word0 + w)*32 + bit.
+        word_k = (pid_k * bkw + jax.lax.broadcasted_iota(
+            jnp.int32, (1, bkw), 1)) * 32
 
         def step(i, acc):
-            s = i * word_chunk
-            a_sl = [r[:, pl.ds(s, word_chunk)] for r in a_refs]
-            b_sl = [r[:, pl.ds(s, word_chunk)] for r in b_refs]
-            av = _unpack_vals(a_sl, ternary_a)     # (bm, wc*32) bf16
-            bv = _unpack_vals(b_sl, ternary_b)     # (bn, wc*32) bf16
-            if not ternary_a:
-                # BNN: zero pad bits decode to +1 on BOTH operands, so
-                # zero the A side past the logical depth (ternary planes
-                # pad to value 0 and cover every other mode).
-                kidx = (pid_k * bkw + s) * 32 + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, word_chunk * 32), 1)
-                av = jnp.where(kidx < k_valid, av, jnp.bfloat16(0))
-            return acc + jnp.dot(av, bv.T,
-                                 preferred_element_type=jnp.float32)
+            for j in range(bc):                # unrolled by hand (Mosaic
+                bit = i * bc + j               # takes no partial unroll)
+                av = _bit_plane_vals(a, bit, ternary_a)   # (bm, bkw) bf16
+                bv = _bit_plane_vals(b, bit, ternary_b)   # (bn, bkw) bf16
+                if not ternary_a:
+                    # BNN: zero pad bits decode to +1 on BOTH operands,
+                    # so zero the A side past the logical depth (ternary
+                    # planes pad to value 0 and cover every other mode).
+                    av = jnp.where(word_k + bit < k_valid, av,
+                                   jnp.bfloat16(0))
+                acc = acc + jax.lax.dot_general(
+                    av, bv, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            return acc
 
-        acc = jax.lax.fori_loop(0, bkw // word_chunk, step,
+        bc = math.gcd(32, max(1, word_chunk))  # bit planes per iteration
+        acc = jax.lax.fori_loop(0, 32 // bc, step,
                                 jnp.zeros(o_ref.shape, jnp.float32))
         o_ref[...] += acc
 
@@ -169,7 +178,7 @@ def dense_matmul_fused_pallas(
         body, list(a_planes), list(b_planes),
         row_operands=[row_scale], col_operands=cols,
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
         acc_dtype=jnp.float32,
     )
 
@@ -193,7 +202,7 @@ def dense_conv_fused_pallas(
     block_n: int = 128,
     block_kw: int = 512,       # accepted for TileConfig uniformity;
     word_chunk: int = 8,       # the conv grid tiles only (m, n)
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     del block_kw, word_chunk
     kh, kw, cin, cout = geometry
@@ -264,7 +273,7 @@ def dense_conv_fused_pallas(
                   + [c_spec] * len(col_ops)),
         out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
-        interpret=interpret,
+        interpret=interpreted_only(interpret, "dense"),
     )(xp, *b_ops, *stat_ops, *col_ops)
     return out[:m, :cout].reshape(bsz, oh, ow, cout)
 
@@ -280,7 +289,7 @@ def _register_dense_kernels():
     # of its own body, so it is fully bound by first kernel dispatch).
 
     def make_gemm(mode):
-        def fn(a, b, k, r, c, bias, *, interpret=True, tiles=None):
+        def fn(a, b, k, r, c, bias, *, interpret=None, tiles=None):
             from repro.kernels import ops
 
             t = ops._resolve_tiles(mode, "dense", True, a, b, k, tiles)
@@ -292,7 +301,7 @@ def _register_dense_kernels():
 
     def make_conv(mode):
         def fn(x, b_planes, geometry, stride, padding, stats, col_scale,
-               bias, *, interpret=True, tiles=None):
+               bias, *, interpret=None, tiles=None):
             t = _resolve_conv_tiles(mode, "dense", x.shape, geometry,
                                     stride, padding, tiles)
             return dense_conv_fused_pallas(mode, x, b_planes, geometry,

@@ -259,7 +259,7 @@ def _register_indexed_kernels():
     # bound by first dispatch) — the plan-key schema stays in one place.
 
     def make(mode, fused):
-        def unfused_fn(a, b, k, *, interpret=True, tiles=None,
+        def unfused_fn(a, b, k, *, interpret=None, tiles=None,
                        payload=None):
             del interpret
             from repro.kernels import ops
@@ -269,7 +269,7 @@ def _register_indexed_kernels():
                                   seg_bits=seg_bits_for(t),
                                   seg_chunk=t.word_chunk, payload=payload)
 
-        def fused_fn(a, b, k, r, c, bias, *, interpret=True, tiles=None,
+        def fused_fn(a, b, k, r, c, bias, *, interpret=None, tiles=None,
                      payload=None):
             del interpret
             from repro.kernels import ops

@@ -4,8 +4,8 @@ ARM original: 4-bit values widened to 8 bits on load, UMLAL into *16-bit*
 lanes (hence the tight k_max = 291 of Table II).
 
 TPU version: operands arrive nibble-packed (two 4-bit values per uint8
-along k, halving HBM traffic); the kernel unpacks to int8 in VMEM and
-feeds the MXU with int32 accumulation.  The paper's 16-bit accumulator
+along k, halving HBM traffic); the kernel splits each byte into its two
+nibbles in VMEM and feeds the int8 MXU with int32 accumulation.  The paper's 16-bit accumulator
 trick does not pay on the MXU (accumulation width is fixed), so k_max
 ceases to be a real constraint — recorded as a hardware-adaptation
 difference; the int16 fidelity semantics live in ref.py.
@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._matmul_common import ceil_to, pad2d
+from repro.kernels._matmul_common import ceil_to, pad2d, resolve_interpret
+from repro.kernels.int8_matmul import int8_dot
 
 __all__ = ["int4_matmul_pallas", "pack_nibbles_rows", "pack_nibbles_cols"]
 
@@ -47,18 +48,6 @@ def pack_nibbles_cols(b_q: jnp.ndarray) -> jnp.ndarray:
     return (v[:, 0, :] | (v[:, 1, :] << 4)).astype(jnp.uint8)
 
 
-def _unpack_rows(packed):      # (bm, bk2) -> (bm, 2*bk2) int32
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = (packed >> 4).astype(jnp.int32)
-    return jnp.stack([lo, hi], axis=-1).reshape(packed.shape[0], -1)
-
-
-def _unpack_cols(packed):      # (bk2, bn) -> (2*bk2, bn) int32
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = (packed >> 4).astype(jnp.int32)
-    return jnp.stack([lo, hi], axis=1).reshape(-1, packed.shape[1])
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k2", "interpret"),
@@ -70,7 +59,7 @@ def int4_matmul_pallas(
     block_m: int = 128,
     block_n: int = 128,
     block_k2: int = 256,     # packed bytes per step == 512 u4 values
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Raw accumulator A_q @ B_q in int32 over nibble-packed operands."""
     m, k2 = a_packed.shape
@@ -88,12 +77,12 @@ def int4_matmul_pallas(
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        a = _unpack_rows(a_ref[...])
-        b = _unpack_cols(b_ref[...])
-        o_ref[...] += jax.lax.dot_general(
-            a, b, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        # Element 2t sits in the low nibble and 2t+1 in the high one on
+        # both operands, so the k sum splits into a low-nibble and a
+        # high-nibble dot — no lane interleave needed to unpack.
+        a = a_ref[...].astype(jnp.int32)
+        b = b_ref[...].astype(jnp.int32)
+        o_ref[...] += int8_dot(a & 0xF, b & 0xF) + int8_dot(a >> 4, b >> 4)
 
     out = pl.pallas_call(
         kernel,
@@ -104,6 +93,6 @@ def int4_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_p, b_p)
     return out[:m, :n]

@@ -2,7 +2,9 @@
 
 ARM original: UMLAL/UMLAL2 8-bit multiply-accumulate into 32-bit lanes.
 TPU version: the MXU natively does int8 x int8 -> int32, so the kernel is
-a standard tiled matmul with ``preferred_element_type=int32``.  The
+a standard tiled matmul of int8 operands with
+``preferred_element_type=int32``.  gemmlowp's unsigned operands arrive
+shifted by -128 (ops.py folds the shift into the zero points).  The
 zero-point correction terms of eq. (3) are rank-1 and O(mk)/O(nk); they
 are applied *outside* the kernel (ops.py), exactly mirroring gemmlowp's
 output pipeline.
@@ -16,9 +18,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels._matmul_common import ceil_to, pad2d
+from repro.kernels._matmul_common import ceil_to, pad2d, resolve_interpret
 
 __all__ = ["int8_matmul_pallas"]
+
+
+def int8_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """(bm, bk) @ (bk, bn) of int8-range int32 values on the int8 MXU,
+    int32 accumulation (the MXU takes no int32 operands)."""
+    return jax.lax.dot_general(
+        a.astype(jnp.int8), b.astype(jnp.int8), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32)
 
 
 @functools.partial(
@@ -26,15 +36,16 @@ __all__ = ["int8_matmul_pallas"]
     static_argnames=("block_m", "block_n", "block_k", "interpret"),
 )
 def int8_matmul_pallas(
-    a_q: jnp.ndarray,   # (m, k) int8/uint8 (quantized values)
-    b_q: jnp.ndarray,   # (k, n) int8/uint8
+    a_q: jnp.ndarray,   # (m, k) int8 (quantized values)
+    b_q: jnp.ndarray,   # (k, n) int8
     *,
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Raw accumulator A_q @ B_q in int32 (first term of eq. (3))."""
+    """Raw accumulator A_q @ B_q in int32 (first term of eq. (3)); the
+    zero padding of k adds nothing."""
     m, k = a_q.shape
     _, n = b_q.shape
     block_k = min(block_k, max(128, k))
@@ -44,18 +55,16 @@ def int8_matmul_pallas(
     b_p = pad2d(b_q, kp, np_)
 
     grid = (mp // block_m, np_ // block_n, kp // block_k)
+    if a_q.dtype != jnp.int8 or b_q.dtype != jnp.int8:
+        raise TypeError(f"int8_matmul_pallas takes int8 operands, got "
+                        f"{a_q.dtype} and {b_q.dtype}")
 
     def kernel(a_ref, b_ref, o_ref):
         @pl.when(pl.program_id(2) == 0)
         def _init():
             o_ref[...] = jnp.zeros_like(o_ref)
 
-        # int8 inputs feed the MXU; accumulate in int32.
-        o_ref[...] += jax.lax.dot_general(
-            a_ref[...].astype(jnp.int32), b_ref[...].astype(jnp.int32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )
+        o_ref[...] += int8_dot(a_ref[...], b_ref[...])
 
     out = pl.pallas_call(
         kernel,
@@ -66,6 +75,6 @@ def int8_matmul_pallas(
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.int32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a_p, b_p)
     return out[:m, :n]

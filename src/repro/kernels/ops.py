@@ -47,6 +47,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 # Leaf first: QuantMode/DEFAULT_BACKEND must be bound before the core
 # import below re-enters this (partially initialized) module through the
@@ -269,12 +270,12 @@ def _register_all_kernels():
     def make_pallas(mode, kernel, fused):
         split = 2 if mode in (M.TNN, M.TBN) else 1  # a-side plane count
 
-        def unfused_fn(a, b, k, *, interpret=True, tiles=None):
+        def unfused_fn(a, b, k, *, interpret=None, tiles=None):
             t = _resolve_tiles(mode, "pallas", False, a, b, k, tiles)
             return kernel(*a[:split], *b, k, interpret=interpret,
                           **t.kernel_kwargs())
 
-        def fused_fn(a, b, k, r, c, bias, *, interpret=True, tiles=None):
+        def fused_fn(a, b, k, r, c, bias, *, interpret=None, tiles=None):
             t = _resolve_tiles(mode, "pallas", True, a, b, k, tiles)
             return kernel(*a[:split], *b, k, r, c, bias,
                           interpret=interpret, **t.kernel_kwargs())
@@ -282,12 +283,12 @@ def _register_all_kernels():
         return fused_fn if fused else unfused_fn
 
     def make_xla(mode, kernel, fused):
-        def unfused_fn(a, b, k, *, interpret=True, tiles=None):
+        def unfused_fn(a, b, k, *, interpret=None, tiles=None):
             del interpret
             t = _resolve_tiles(mode, "xla", False, a, b, k, tiles)
             return kernel(*a, *b, k, word_chunk=t.word_chunk)
 
-        def fused_fn(a, b, k, r, c, bias, *, interpret=True, tiles=None):
+        def fused_fn(a, b, k, r, c, bias, *, interpret=None, tiles=None):
             del interpret
             t = _resolve_tiles(mode, "xla", True, a, b, k, tiles)
             return kernel(*a, *b, k, r, c, bias, word_chunk=t.word_chunk)
@@ -335,7 +336,7 @@ def _register_all_kernels():
             description="popcount scan; epilogue fused onto the final carry",
         )(make_xla(mode, xla_kernels[(mode, True)], fused=True))
 
-        def dense_unfused(a, b, k, *, interpret=True, tiles=None, _m=mode):
+        def dense_unfused(a, b, k, *, interpret=None, tiles=None, _m=mode):
             del interpret, tiles    # XLA picks the dense tiling itself
             av = _unpack_operand(a, k, binary=not ternary_a[_m])
             bv = _unpack_operand(b, k, binary=not ternary_b[_m])
@@ -369,11 +370,13 @@ def _affine_core(mode: QuantMode, a_pl, b_pl, k_valid: int, *,
     b_q, zb = b_pl
     if use_pallas:
         if mode == QuantMode.INT8:
-            # gemmlowp's operands are *unsigned* 8-bit; widen from uint8
-            # so the 0..255 range survives (an int8 cast would wrap
-            # 128..255).
-            acc = int8_matmul_pallas(a_q.astype(jnp.uint8),
-                                     b_q.astype(jnp.uint8),
+            # gemmlowp's operands are *unsigned* 8-bit and the MXU takes
+            # int8: shift both grids by -128 and their zero points with
+            # them, (a - za) = (a-128) - (za-128), so eq. (3) is unchanged.
+            a_q, b_q = (jnp.asarray(q, jnp.int32) - 128 for q in (a_q, b_q))
+            za, zb = (jnp.asarray(z, jnp.int32) - 128 for z in (za, zb))
+            acc = int8_matmul_pallas(a_q.astype(jnp.int8),
+                                     b_q.astype(jnp.int8),
                                      interpret=interpret)
         else:
             acc = int4_matmul_pallas(pack_nibbles_rows(a_q),
@@ -392,12 +395,12 @@ def _affine_core(mode: QuantMode, a_pl, b_pl, k_valid: int, *,
 
 def _register_affine_kernels():
     def make(mode, use_pallas, fused):
-        def unfused_fn(a, b, k, *, interpret=True, tiles=None):
+        def unfused_fn(a, b, k, *, interpret=None, tiles=None):
             del tiles                # the int kernels pick their own tiling
             return _affine_core(mode, a, b, k, use_pallas=use_pallas,
                                 interpret=interpret)
 
-        def fused_fn(a, b, k, r, c, bias, *, interpret=True, tiles=None):
+        def fused_fn(a, b, k, r, c, bias, *, interpret=None, tiles=None):
             del tiles
             acc = _affine_core(mode, a, b, k, use_pallas=use_pallas,
                                interpret=interpret)
@@ -449,7 +452,7 @@ def _affine_backend(mode: QuantMode, backend: str, *, fused: bool) -> str:
 
 def int8_affine_matmul(a_q, b_q, za, zb, k_valid: int, *,
                        backend: str = DEFAULT_BACKEND,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """c~ per eq. (3).  a_q (m,k) u8-valued, b_q (k,n) u8-valued."""
     spec = registry.lookup(QuantMode.INT8,
                            _affine_backend(QuantMode.INT8, backend,
@@ -459,7 +462,7 @@ def int8_affine_matmul(a_q, b_q, za, zb, k_valid: int, *,
 
 def int4_affine_matmul(a_q, b_q, za, zb, k_valid: int, *,
                        backend: str = DEFAULT_BACKEND,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     spec = registry.lookup(QuantMode.INT4,
                            _affine_backend(QuantMode.INT4, backend,
                                            fused=False), fused=False)
@@ -506,6 +509,12 @@ def quantize_activations(x: jnp.ndarray, mode: QuantMode, *,
     """
     if mode in (QuantMode.F32, QuantMode.BF16):
         return {"x": x}
+    if x.ndim == 2:
+        # The per-tensor statistics are reductions over all of x, and
+        # their order follows x's layout, which XLA may otherwise choose
+        # to suit whichever kernel consumes the planes: pin x row-major
+        # so every backend quantizes with bit-identical scalars.
+        x = with_layout_constraint(x, Layout(major_to_minor=(0, 1)))
     if mode in (QuantMode.TNN, QuantMode.TBN):
         if stats is not None:
             t, _ = quantize.ternarize(x, threshold=stats["thr"])
@@ -541,7 +550,7 @@ def packed_matmul(xa: Dict[str, Any], wb: QTensor,
                   mode: Optional[QuantMode] = None,
                   k_valid: Optional[int] = None, *,
                   backend: str = DEFAULT_BACKEND,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool | None = None) -> jnp.ndarray:
     """Integer core: packed activations x packed weights -> int32 (m, n).
 
     ``wb`` is a :class:`QTensor` (mode/k_valid come from it; the legacy
@@ -605,10 +614,11 @@ _QMM_DISPATCH_CTR = obs.get_registry().counter(
 
 
 # ---------------------------------------------------------------------------
-# Graceful-degradation fallback chain (docs/resilience.md): when a
-# backend fails to build/lower — or the fault plane injects
-# "kernel.compile" — dispatch walks pallas -> xla -> dense oracle
-# instead of propagating.  The landed decision is cached per
+# Graceful-degradation fallback chain (docs/resilience.md): when the
+# fault plane injects "kernel.compile", dispatch walks pallas -> xla ->
+# dense oracle instead of propagating.  Only the injected fault
+# degrades: a real lowering error raises, so a kernel that does not
+# compile for the device is never timed as if it had run.  The landed decision is cached per
 # (op, mode, requested backend) ~ per KernelSpec, so the hot path never
 # retries a dead backend per call: after the first degradation every
 # subsequent call is one dict lookup straight to the surviving backend.
@@ -731,7 +741,7 @@ def _qmm_oracle_jit(x, qt: QTensor, interpret: bool, act_stats=None):
 
 
 def qmm(x: jnp.ndarray, qt: QTensor, *, backend: Optional[str] = None,
-        interpret: bool = True,
+        interpret: bool | None = None,
         act_stats: Optional[Dict[str, Any]] = None) -> jnp.ndarray:
     """Quantized matmul: float ``x`` (m, k) against an offline-packed
     :class:`QTensor` -> float32 (m, n), in ONE jitted computation.
@@ -773,8 +783,9 @@ def qmm(x: jnp.ndarray, qt: QTensor, *, backend: Optional[str] = None,
     backend : str, optional
         "pallas" | "xla" | "dense" | "indexed"; None ->
         :data:`DEFAULT_BACKEND`.
-    interpret : bool
-        Run Pallas kernels in interpret mode (CPU validation).
+    interpret : bool, optional
+        Pallas interpret mode; None (default) interprets on the CPU
+        backend only (``_matmul_common.resolve_interpret``).
     act_stats : dict, optional
         Overrides the per-tensor activation quantization statistics
         (see :func:`quantize_activations`) — the materializing conv
@@ -859,7 +870,7 @@ def qmm(x: jnp.ndarray, qt: QTensor, *, backend: Optional[str] = None,
                                             k=qt.k_valid).tiles
             return _qmm_jit(x, qt, backend=backend, interpret=interpret,
                             tiles=tiles, act_stats=act_stats)
-        except Exception as e:
+        except faults.InjectedFault as e:
             nxt = _fallback_next(qt.mode, backend)
             if nxt is None:
                 raise
@@ -937,7 +948,7 @@ def _qconv_oracle_jit(x, qt: QTensor, act_stats, stride: int, padding: str,
 
 def qconv(x: jnp.ndarray, qt: QTensor, *, stride: int = 1,
           padding: str = "SAME", backend: Optional[str] = None,
-          interpret: bool = True,
+          interpret: bool | None = None,
           act_stats: Optional[Dict[str, Any]] = None) -> jnp.ndarray:
     """Fused-im2col packed conv: float ``x`` (B, H, W, Cin) against a
     conv QTensor (``pack_conv_filters``) -> float32 (B, OH, OW, Cout) in
@@ -967,8 +978,9 @@ def qconv(x: jnp.ndarray, qt: QTensor, *, stride: int = 1,
         "pallas" | "xla" | "dense"; None -> :data:`DEFAULT_BACKEND`.
         The fused-im2col kernel for (mode, backend) must be registered
         (:func:`has_conv_kernel`).
-    interpret : bool
-        Run Pallas kernels in interpret mode (CPU validation).
+    interpret : bool, optional
+        Pallas interpret mode; None (default) interprets on the CPU
+        backend only (``_matmul_common.resolve_interpret``).
     act_stats : dict, optional
         Pre-computed shared activation statistics
         (``conv_fused.conv_act_stats``); None derives them from ``x``.
@@ -1047,7 +1059,7 @@ def qconv(x: jnp.ndarray, qt: QTensor, *, stride: int = 1,
             return _qconv_jit(x, qt, act_stats, backend=backend,
                               stride=stride, padding=padding,
                               interpret=interpret, tiles=tiles)
-        except Exception as e:
+        except faults.InjectedFault as e:
             nxt = _fallback_next(qt.mode, backend, conv=True)
             if nxt is None:
                 raise
@@ -1058,7 +1070,7 @@ def qconv(x: jnp.ndarray, qt: QTensor, *, stride: int = 1,
 def fused_qmm(x: jnp.ndarray, wb, mode: Optional[QuantMode] = None,
               bias: Optional[jnp.ndarray] = None, *,
               backend: str = DEFAULT_BACKEND,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: bool | None = None) -> jnp.ndarray:
     """DEPRECATED legacy shim for the pre-QTensor API — call
     ``qmm(x, qt)`` directly (``QTensor.from_legacy_dict`` migrates old
     packed dicts).  Kept for one release; emits a DeprecationWarning and
@@ -1105,7 +1117,7 @@ def _qmm_fwd_value(x, w, mode: QuantMode, backend: str, interpret: bool):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
 def quantized_matmul(x, w, mode: QuantMode = QuantMode.TNN,
-                     backend: str = DEFAULT_BACKEND, interpret: bool = True):
+                     backend: str = DEFAULT_BACKEND, interpret: bool | None = None):
     """y ~= x @ w computed through the selected quantized pipeline.
 
     Gradients are straight-through at matmul granularity (standard for
@@ -1135,7 +1147,7 @@ quantized_matmul.defvjp(_qmm_fwd, _qmm_bwd)
 
 def lowbit_matmul(a: jnp.ndarray, b: jnp.ndarray, mode: QuantMode, *,
                   backend: str = DEFAULT_BACKEND,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool | None = None) -> jnp.ndarray:
     """Exact integer matmul of {-1,0,1}-valued dense matrices through the
     packed pipeline (test/bench entry; no scales)."""
     k = a.shape[-1]

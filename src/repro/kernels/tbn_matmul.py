@@ -61,7 +61,7 @@ def tbn_matmul_pallas(
     block_n: int = _TILES.block_n,
     block_kw: int = _TILES.block_kw,
     word_chunk: int = _TILES.word_chunk,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     del k_valid
 
@@ -77,7 +77,7 @@ def tbn_matmul_pallas(
     return lowbit_matmul_call(
         body, [a_plus, a_minus], [b_bits_t],
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
     )
 
 
@@ -99,7 +99,7 @@ def tbn_matmul_fused_pallas(
     block_n: int = _TILES.block_n,
     block_kw: int = _TILES.block_kw,
     word_chunk: int = _TILES.word_chunk,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Table I products + eq. (2) in one pass: float32 (m, n) output."""
     del k_valid
@@ -122,6 +122,6 @@ def tbn_matmul_fused_pallas(
         body, [a_plus, a_minus], [b_bits_t],
         row_operands=[row_scale], col_operands=cols,
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
         acc_dtype=jnp.float32,
     )

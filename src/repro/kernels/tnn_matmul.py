@@ -64,7 +64,7 @@ def tnn_matmul_pallas(
     block_n: int = _TILES.block_n,
     block_kw: int = _TILES.block_kw,
     word_chunk: int = _TILES.word_chunk,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     del k_valid  # exact without correction; kept for a uniform signature
 
@@ -80,7 +80,7 @@ def tnn_matmul_pallas(
     return lowbit_matmul_call(
         body, [a_plus, a_minus], [b_plus_t, b_minus_t],
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
     )
 
 
@@ -102,7 +102,7 @@ def tnn_matmul_fused_pallas(
     block_n: int = _TILES.block_n,
     block_kw: int = _TILES.block_kw,
     word_chunk: int = _TILES.word_chunk,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jnp.ndarray:
     """eq. (7) + eq. (2) in one pass: float32 (m, n) output."""
     del k_valid  # exact without correction; kept for a uniform signature
@@ -125,6 +125,6 @@ def tnn_matmul_fused_pallas(
         body, [a_plus, a_minus], [b_plus_t, b_minus_t],
         row_operands=[row_scale], col_operands=cols,
         block_m=block_m, block_n=block_n, block_kw=block_kw,
-        word_chunk=word_chunk, interpret=interpret,
+        interpret=interpret,
         acc_dtype=jnp.float32,
     )

@@ -19,6 +19,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "make_serve_mesh",
            "POD_SHAPE"]
@@ -38,7 +39,10 @@ def make_mesh(shape, axes, devices=None):
             f"need {n} devices for mesh {shape}, have {len(devs)} — the "
             f"dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             f"device_count=512 before importing jax")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    # Auto axes: the model code places arrays through sharding rules and
+    # with_sharding_constraint, not through explicitly-typed mesh axes.
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -14,6 +14,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, get_smoke
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import model as model_mod
 from repro.models.common import ShardLayout
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.8)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     over = {"quant_policy": args.quant} if args.quant else {}
     cfg = (get_smoke(args.arch, **over) if args.smoke

@@ -17,6 +17,7 @@ import jax
 
 from repro.configs import get_config, get_smoke
 from repro.data import SyntheticLM
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.common import ShardLayout
 from repro.optim.adamw import AdamWConfig
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--checkpoint-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     over = {"quant_policy": args.quant} if args.quant else {}
     cfg = (get_smoke(args.arch, **over) if args.smoke

@@ -141,7 +141,7 @@ def project(params: Dict[str, Any] | QTensor, x: jnp.ndarray,
         y = jnp.dot(x2.astype(jnp.float32), w.astype(jnp.float32))
     else:
         y = ops.quantized_matmul(x2.astype(jnp.float32),
-                                 w.astype(jnp.float32), mode, backend, True)
+                                 w.astype(jnp.float32), mode, backend)
     return y.reshape(*lead, w.shape[-1]).astype(x.dtype)
 
 

@@ -77,7 +77,7 @@ def _expert_matmul(w, h: jnp.ndarray, mode: QuantMode,
         return jnp.einsum("eck,ekn->ecn", h.astype(ct), w.astype(ct),
                           preferred_element_type=jnp.float32).astype(h.dtype)
     qmm = jax.vmap(lambda a, b: ops.quantized_matmul(
-        a.astype(jnp.float32), b.astype(jnp.float32), mode, backend, True))
+        a.astype(jnp.float32), b.astype(jnp.float32), mode, backend))
     return qmm(h, w).astype(h.dtype)
 
 

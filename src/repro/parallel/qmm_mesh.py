@@ -44,7 +44,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels._matmul_common import TileConfig, psum_accum_dtype
@@ -258,13 +257,13 @@ def _qmm_mesh_jit(x, qt: QTensor, act_stats, *, backend: str,
     if has_stats:
         args.append(act_stats)
         specs.append(jax.tree.map(lambda _: P(), act_stats))
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=P(None, n_ax), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=P(None, n_ax), check_vma=False)
     return fn(*args)
 
 
 def qmm_sharded(x, qt: QTensor, plan: ShardPlan, mesh: Mesh, *,
-                backend: str, interpret: bool = True,
+                backend: str, interpret: bool | None = None,
                 act_stats: Optional[Dict[str, Any]] = None):
     """Mesh-aware qmm entry (called by ops.qmm once a plan resolved).
 
@@ -333,14 +332,15 @@ def _qconv_mesh_jit(x, qt: QTensor, act_stats, *, backend: str, stride: int,
         specs.append(P(None, n_ax))
     args.append(act_stats)
     specs.append(jax.tree.map(lambda _: P(), act_stats))
-    fn = shard_map(body, mesh=mesh, in_specs=tuple(specs),
-                   out_specs=P(None, None, None, n_ax), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                       out_specs=P(None, None, None, n_ax),
+                       check_vma=False)
     return fn(*args)
 
 
 def qconv_sharded(x, qt: QTensor, plan: ShardPlan, mesh: Mesh, act_stats, *,
                   backend: str, stride: int, padding: str,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """Mesh-aware qconv: each device runs the fused-im2col kernel over
     its cout slice (geometry shrinks to cout_local); the input image and
     the shared activation statistics are replicated, so no collective is

@@ -159,9 +159,7 @@ _ACTIVE: contextvars.ContextVar[Optional[_Active]] = \
 def use_mesh(mesh: Mesh, rules: Rules = TRAIN_RULES):
     tok = _ACTIVE.set(_Active(mesh, rules))
     try:
-        # jax.set_mesh landed after 0.4.x; on older jax the Mesh context
-        # manager provides the same ambient-mesh behaviour for jit/pjit.
-        with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+        with jax.set_mesh(mesh):
             yield
     finally:
         _ACTIVE.reset(tok)
@@ -247,7 +245,11 @@ _PARAM_RULES: Tuple[Tuple[str, Tuple[Optional[str], ...]], ...] = (
     (r"conv_w$",             (None, "conv_dim")),
     (r"conv_b$",             ("conv_dim",)),
     (r"(A_log|D|dt_bias)$",  ("ssm_heads",)),
-    (r"norm$",               ("conv_dim",)),            # ssm gated norm (din,)
+    # ssm gated norm (din,).  Anchored to a path segment: the moment
+    # fallback of param_spec strips "/scale", and "pre_mixer_norm" must
+    # not match — sharding an RMSNorm scale splits the norm's sum of
+    # squares over devices, another summation order than one chip's.
+    (r"(^|/)norm$",          ("conv_dim",)),
     # ---- packed bit-planes (serving) ----
     (r"(wq|wk|wv)/(?:payload/)?(plus|minus|bits)$", ("heads", "fsdp")),
     (r"(wq|wk|wv)/scale$",   ("heads",)),

@@ -176,11 +176,12 @@ CONV_PALLAS_SPACE = TuningSpace(kind="pallas",
                                 word_chunk=(4, 8))
 
 # Dense-backend (MXU) fused GeMM kernels (kernels/dense_fused.py): the
-# grid axes mirror the popcount kernels, but each inner step unpacks a
-# ``word_chunk``-word slice to a (block, word_chunk*32)-element ±1/0
-# bf16 tile and feeds one MXU dot — so word_chunk here sets the k extent
-# of every dot (128/256 elements) and block_kw the VMEM-resident word
-# depth between output revisits.
+# grid axes mirror the popcount kernels, but each inner step decodes one
+# bit plane of the (block, block_kw) word tiles to ±1/0 bf16 and feeds
+# one MXU dot of depth block_kw — so block_kw sets the k extent of every
+# dot and the VMEM-resident word depth between output revisits (below
+# 128 words it runs as the whole word extent, the (8, 128) block rule),
+# and word_chunk the bit planes unrolled per loop iteration.
 DENSE_SPACE = TuningSpace(kind="pallas",
                           block_m=(8, 32, 128),
                           block_n=(128, 256),

@@ -163,7 +163,7 @@ def tune_one(mode: QuantMode, backend: str, *, fused: bool = True,
              k: Optional[int] = None,
              space: Optional[TuningSpace] = None,
              reps: int = 3, warmup: int = 1, seed: int = 0,
-             interpret: bool = True,
+             interpret: bool | None = None,
              conv: Optional[ConvProblem] = None,
              ) -> Tuple[plan_cache.Plan, Dict]:
     """Measure every candidate blocking for one problem and return the
@@ -248,7 +248,7 @@ def ensure_plan(mode: QuantMode, backend: str, *, fused: bool = True,
                 m: Optional[int] = None, n: Optional[int] = None,
                 k: Optional[int] = None,
                 reps: int = 3, warmup: int = 1, seed: int = 0,
-                interpret: bool = True, save: bool = True,
+                interpret: bool | None = None, save: bool = True,
                 reports: Optional[Dict[str, Dict]] = None,
                 conv: Optional[ConvProblem] = None,
                 ) -> Tuple[plan_cache.Plan, bool]:
@@ -317,7 +317,7 @@ def tune_shapes(shapes: Iterable[Tuple[int, int, int]],
                 modes: Sequence[QuantMode],
                 backends: Sequence[str], *,
                 fused: bool = True, reps: int = 3, warmup: int = 1,
-                seed: int = 0, interpret: bool = True,
+                seed: int = 0, interpret: bool | None = None,
                 verbose: bool = False,
                 conv_problems: Sequence[ConvProblem] = (),
                 ) -> Tuple[List[plan_cache.Plan], Dict[str, int],

@@ -12,6 +12,7 @@ from repro.configs import get_smoke
 from repro.kernels._matmul_common import psum_accum_dtype
 from repro.kernels.ops import QuantMode
 from repro.kernels.qtensor import QTensor
+from repro.launch.mesh import make_mesh
 from repro.models import model as model_mod
 from repro.models.common import ShardLayout
 from repro.models.packing import pack_lm_params
@@ -99,8 +100,7 @@ def test_pack_lm_params_records_pspec_on_1x1_mesh():
                                             quant_policy="tnn")
     layout = ShardLayout(tp=1)
     params = model_mod.init_lm(jax.random.PRNGKey(0), cfg, layout)
-    mesh = jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    mesh = make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
     with sharding.use_mesh(mesh, sharding.SERVE_RULES_LOWBIT):
         packed = pack_lm_params(params, cfg)
         qts = [t for t in jax.tree_util.tree_flatten(

@@ -105,6 +105,27 @@ def test_packed_qtensor_plane_specs():
     assert specs["blocks/0/mixer/wq/scale"] == P("model")
 
 
+def test_rmsnorm_scales_replicate_ssm_gated_norm_shards():
+    """An RMSNorm scale ("pre_mixer_norm/scale", "final_norm/scale")
+    replicates: sharding it splits the norm's sum of squares over
+    devices, and the mesh engine then normalizes in another summation
+    order than one chip (regression: the moment '/scale' strip let
+    "pre_mixer_norm" meet the SSM rule ``norm$``).  The SSM gated norm
+    leaf ("mixer/norm") still shards over conv_dim."""
+    ctx = _Ctx({"data": 4, "model": 4})
+    tree = {
+        "final_norm": {"scale": jnp.zeros((64,))},
+        "blocks": [{"pre_mixer_norm": {"scale": jnp.zeros((2, 64))},
+                    "mixer": {"norm": jnp.zeros((64,))}}],
+    }
+    flat = jax.tree_util.tree_flatten_with_path(tree)[0]
+    specs = {sharding._path_str(p): sharding.param_spec(p, v, ctx)
+             for p, v in flat}
+    assert specs["final_norm/scale"] == P(None)
+    assert specs["blocks/0/pre_mixer_norm/scale"] == P(None, None)
+    assert specs["blocks/0/mixer/norm"] == P("model")
+
+
 def test_pad_helpers():
     lay = ShardLayout(tp=16)
     assert lay.pad_heads(24) == 32

@@ -1,0 +1,155 @@
+"""Compile-only guard: the main-path kernels lower for a described TPU v5e.
+
+Nothing here runs on a chip.  A ``v5e:2x2`` topology is described inside
+a module-scoped fixture (never at import: only one process at a time may
+load the TPU library, and every test worker imports this file), and each
+test compiles its program for the described devices with
+``interpret=False`` — what Mosaic would refuse on the chip (unaligned
+lane slices, block shapes off the (8, 128) tiling, unsupported ops)
+fails here at no chip time.  Widths are tinyllama-1.1b's FFN
+(k = d_model = 2048, n = d_ff = 5632) at a decode m (8) and a prefill
+m (256).
+"""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops, registry
+from repro.kernels._matmul_common import DEFAULT_TILES
+from repro.kernels.modes import QuantMode
+from repro.kernels.qtensor import QTensor
+from repro.parallel import qmm_mesh
+
+K, N = 2048, 5632
+LOWBIT = (QuantMode.TNN, QuantMode.TBN, QuantMode.BNN)
+_A_PLANES = {QuantMode.BNN: 1, QuantMode.TNN: 2, QuantMode.TBN: 2}
+_B_PLANES = {QuantMode.BNN: 1, QuantMode.TNN: 2, QuantMode.TBN: 1}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # A compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep the cache off.
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _packed_struct(mode, sharding, shape=(K, N)):
+    """Shape-only QTensor of a (k, n) weight, every leaf on ``sharding``."""
+    qt = jax.eval_shape(lambda w: QTensor.from_dense(w, mode),
+                        jax.ShapeDtypeStruct(shape, jnp.float32))
+    return jax.tree.map(lambda l: _struct(l.shape, l.dtype, sharding), qt)
+
+
+@pytest.mark.parametrize("m", [8, 256])
+@pytest.mark.parametrize("mode", LOWBIT, ids=lambda md: md.value)
+@pytest.mark.parametrize("backend", ["pallas", "dense"])
+def test_fused_gemm_kernel_compiles_for_v5e(one_chip, backend, mode, m):
+    kw = K // 32
+    spec = registry.lookup(mode, backend, fused=True)
+    a = tuple(_struct((m, kw), jnp.uint32, one_chip)
+              for _ in range(_A_PLANES[mode]))
+    b = tuple(_struct((N, kw), jnp.uint32, one_chip)
+              for _ in range(_B_PLANES[mode]))
+    row = _struct((m, 1), jnp.float32, one_chip)
+    col = _struct((1, N), jnp.float32, one_chip)
+
+    def fn(a, b, row, col):
+        return spec.fn(a, b, K, row, col, None, interpret=False,
+                       tiles=DEFAULT_TILES[mode.value])
+
+    compiled = jax.jit(fn).lower(a, b, row, col).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("mode", LOWBIT, ids=lambda md: md.value)
+def test_xla_qmm_compiles_for_v5e(one_chip, mode):
+    x = _struct((8, K), jnp.float32, one_chip)
+    qt = _packed_struct(mode, one_chip)
+    compiled = ops._qmm_jit.lower(x, qt, backend="xla",
+                                  interpret=False).compile()
+    assert compiled.memory_analysis() is not None
+
+
+def test_xla_qmm_quantizes_a_row_major_activation(one_chip):
+    # The xla scan consumes the activation planes word-major, and XLA
+    # would otherwise make a fused producer (the FFN's silu(gate) * up)
+    # column-major, reordering the per-tensor statistics reductions: the
+    # popcount and MXU engines then quantize with different scale bits
+    # and their tokens drift apart.
+    qt = _packed_struct(QuantMode.TNN, one_chip, shape=(N, K))
+    gate = _struct((256, N), jnp.bfloat16, one_chip)
+
+    def down(g, u, q):
+        h = (jax.nn.silu(g) * u).astype(jnp.float32)
+        return ops._qmm_jit(h, q, backend="xla", interpret=False)
+
+    text = jax.jit(down).lower(gate, gate, qt).compile().as_text()
+    layouts = set(re.findall(rf"f32\[256,{N}\]\{{([0-9,]+):", text))
+    assert layouts == {"1,0"}, layouts
+
+
+@pytest.mark.parametrize("m", [8, 256])
+@pytest.mark.parametrize("mode", [QuantMode.INT8, QuantMode.INT4],
+                         ids=lambda md: md.value)
+def test_affine_pallas_qmm_compiles_for_v5e(one_chip, mode, m):
+    # The int8 MXU takes int8 operands only: gemmlowp's u8 grids arrive
+    # shifted by -128, u4 nibbles split into two int8 dots.
+    x = _struct((m, K), jnp.float32, one_chip)
+    qt = _packed_struct(mode, one_chip)
+    compiled = ops._qmm_jit.lower(x, qt, backend="pallas",
+                                  interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_k_sharded_qmm_compiles_on_four_v5e_chips(topo, backend):
+    # model=4 splits the 64 words of k=2048 into 16 per shard: the
+    # per-device kernel sees a (m, 16) word block, whole on its lane axis.
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    repl = NamedSharding(mesh, P())
+    plane = NamedSharding(mesh, P(None, "model"))
+    mode = QuantMode.TNN
+    qt = _packed_struct(mode, repl)
+    qt = qt.replace(payload={key: _struct(v.shape, v.dtype, plane)
+                             for key, v in qt.payload.items()},
+                    pspec=(None, "model"))
+    plan = qmm_mesh.ShardPlan(k_axis="model", k_shards=4, acc_dtype="int16")
+    x = _struct((8, K), jnp.float32, repl)
+    compiled = qmm_mesh._qmm_mesh_jit.lower(
+        x, qt, None, backend=backend, interpret=False, mesh=mesh, plan=plan,
+        tiles=DEFAULT_TILES[mode.value]).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text
+    if backend == "pallas":
+        assert "tpu_custom_call" in text
