@@ -828,6 +828,15 @@ def qmm(x: jnp.ndarray, qt: QTensor, *, backend: Optional[str] = None,
     backend = _FB_DECISION.get(("qmm", qt.mode, requested), requested)
     _QMM_DISPATCH_CTR.inc(mode=qt.mode.value, backend=backend,
                           layout=registry.LAYOUT_GEMM)
+    # a stable name for the call's ops in compiled programs and device
+    # traces (metadata only: the compiled program is otherwise the same)
+    with jax.named_scope(f"qmm[{qt.mode.value}]"):
+        return _qmm_dispatch(x, qt, requested, backend, interpret, act_stats)
+
+
+def _qmm_dispatch(x, qt: QTensor, requested: str, backend: str,
+                  interpret, act_stats):
+    """``qmm`` past its checks: the mesh path, or the backend chain."""
     if qt.is_lowbit:
         from repro.parallel import qmm_mesh, sharding
 
@@ -1015,8 +1024,20 @@ def qconv(x: jnp.ndarray, qt: QTensor, *, stride: int = 1,
     backend = _FB_DECISION.get(("qconv", qt.mode, requested), requested)
     _QCONV_DISPATCH_CTR.inc(mode=qt.mode.value, backend=backend,
                             layout=registry.LAYOUT_IM2COL)
+    # the activation statistics and the kernel under one stable name
+    # (metadata only, as in qmm)
+    with jax.named_scope(f"qconv[{qt.mode.value}]"):
+        return _qconv_dispatch(x, qt, stride, padding, requested, backend,
+                               interpret, act_stats)
+
+
+def _qconv_dispatch(x, qt: QTensor, stride: int, padding: str,
+                    requested: str, backend: str, interpret, act_stats):
+    """``qconv`` past its checks: statistics, then the mesh path or the
+    backend chain."""
     from repro.kernels import conv_fused
 
+    kh, kw_ = qt.geometry[:2]
     if act_stats is None:
         act_stats = conv_fused.conv_act_stats(x, qt.mode, kh, kw_,
                                               stride, padding)

@@ -242,8 +242,13 @@ def page_view(entry: Dict[str, Any], dh: int
     bias (pad bits encode (0,0) = exact 0, so no depth correction is
     needed).  Unallocated logical pages resolve to the scratch page,
     whose positions stay ``INVALID_POS`` and fail every ``pos <= step``
-    mask.
+    mask.  Its ops carry the scope ``kv_page_view`` (metadata only).
     """
+    with jax.named_scope("kv_page_view"):
+        return _page_view(entry, dh)
+
+
+def _page_view(entry, dh):
     n_pages, page, npp = entry_geometry(entry)
     table = entry["page_table"]                    # (B, npp)
     b = table.shape[0]

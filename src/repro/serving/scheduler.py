@@ -27,6 +27,13 @@ Slot lifecycle (chunked)::
 Every tick runs at most two jitted calls — one (B, prefill_chunk) chunk
 and one (B, 1) decode — so the engine traces exactly two shapes no
 matter how requests overlap.
+
+With obs on, each tick is an ``engine/tick`` profiler span (stat
+``tick``) holding one ``sched/*`` span per host step: ``expire``,
+``admit`` (``uids``), ``release`` (``uid``), ``page_sync``, ``inputs``,
+``prefill_wait``, ``logits_pull`` (``bytes``, ``uids``),
+``numeric_guard``, ``token_wait`` and ``emit``.  They add no device op
+and no sync (docs/observability.md, "Profiler hooks").
 """
 
 from __future__ import annotations
@@ -40,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import paged_kvcache as paged
 from repro.models.kvcache import INVALID_POS
 from repro.resilience import faults
@@ -111,6 +119,7 @@ class Scheduler:
         self.last_token = np.zeros(b, np.int32)
         self.slot_req: List[Optional[Request]] = [None] * b
         self.results: Dict[int, Result] = {}
+        self.ticks = 0                                 # step() calls
         # uid -> [pre-sampling logits row per step] when the engine was
         # built with ServeConfig.trace_logits (None otherwise).
         self.logit_trace: Optional[Dict[int, List[np.ndarray]]] = (
@@ -131,16 +140,20 @@ class Scheduler:
     def step(self) -> bool:
         """One tick: expire/cancel, admit+prefill, decode.  Returns True
         while any request is queued or in flight."""
-        self.expire()
-        faults.maybe_stall("step.stall")
-        self.admit_once()
-        # Fired between admission and decode so in-flight slots exist
-        # when the loss lands — the hardest spot to recover from.
-        faults.maybe_raise("device.loss")
-        self.decode_once()
-        self.eng.obs.tick(len(self.queue),
-                          sum(1 for u in self.slot_uid if u != -1),
-                          self.page_stats())
+        self.ticks += 1
+        with obs.annotate("engine/tick", tick=self.ticks):
+            with obs.annotate("sched/expire"):
+                self.expire()
+            faults.maybe_stall("step.stall")
+            self.admit_once()
+            # Fired between admission and decode so in-flight slots exist
+            # when the loss lands — the hardest spot to recover from.
+            faults.maybe_raise("device.loss")
+            self.decode_once()
+            if self.eng.obs.enabled:
+                self.eng.obs.tick(len(self.queue),
+                                  sum(1 for u in self.slot_uid if u != -1),
+                                  self.page_stats())
         return bool(self.queue or any(u != -1 for u in self.slot_uid))
 
     def page_stats(self) -> List:
@@ -182,10 +195,16 @@ class Scheduler:
             self.slot_uid[b], self.slot_tokens[b], status=status)
         self.eng.obs.on_finish(self.slot_uid[b], status,
                                len(self.slot_tokens[b]))
+        self._free(b)
+
+    def _free(self, b: int) -> None:
+        """Empty slot ``b`` and give back what it holds."""
+        uid = self.slot_uid[b]
         self.slot_uid[b] = -1
         self.slot_tokens[b] = []
         self.slot_req[b] = None
-        self.release(b)
+        with obs.annotate("sched/release", uid=uid):
+            self.release(b)
 
     def release(self, b: int) -> None:          # pages, in the paged case
         pass
@@ -236,10 +255,7 @@ class Scheduler:
                     scfg.retry_backoff_cap_s)
         req.not_before = self.clock() + delay
         self.eng.obs.on_preempt(req.uid, cause, req.retries, delay)
-        self.slot_uid[b] = -1
-        self.slot_tokens[b] = []
-        self.slot_req[b] = None
-        self.release(b)
+        self._free(b)
         self.queue.append(req)
 
     def quarantine(self, exc: BaseException) -> None:
@@ -258,10 +274,7 @@ class Scheduler:
         it — ``unfinished()`` is how callers migrate the remainder)."""
         for b in range(len(self.slot_uid)):
             if self.slot_uid[b] != -1:
-                self.slot_uid[b] = -1
-                self.slot_tokens[b] = []
-                self.slot_req[b] = None
-                self.release(b)
+                self._free(b)
 
     def unfinished(self) -> List[Request]:
         """Queued plus in-flight requests, admission order first — what
@@ -275,6 +288,43 @@ class Scheduler:
 
     def decode_once(self) -> None:
         raise NotImplementedError
+
+    def _emit_decoded(self, rows: List[int], nxt, last_logits) -> None:
+        """Wait for a decode step's tokens and hand them to slots
+        ``rows``.  The NaN/Inf guard's reduce is dispatched behind the
+        step before the wait and pulled after it, so the wait for the
+        step is all in ``sched/token_wait``."""
+        scfg = self.eng.scfg
+        fin = None
+        if scfg.numeric_guard:
+            with obs.annotate("sched/numeric_guard"):
+                fin = jnp.all(jnp.isfinite(last_logits), axis=-1)
+        with obs.annotate("sched/token_wait"):
+            nxt = np.asarray(nxt)
+        if fin is not None:
+            with obs.annotate("sched/numeric_guard"):
+                fin = np.asarray(fin)
+        with obs.annotate("sched/emit"):
+            if self.logit_trace is not None:
+                lg = np.asarray(last_logits)
+                for b in rows:
+                    self.trace(self.slot_uid[b], lg[b])
+            for b in rows:
+                if fin is not None and not fin[b]:
+                    # Poisoned logits: the sampled token is garbage —
+                    # resolve the stream instead of emitting NaN-derived
+                    # tokens.
+                    self.finish(b, status="numeric_error")
+                    continue
+                self.slot_tokens[b].append(int(nxt[b]))
+                self.last_token[b] = nxt[b]
+                self.slot_pos[b] += 1
+                self.slot_remaining[b] -= 1
+                self.eng.obs.on_decode_token(self.slot_uid[b])
+                if (self.slot_remaining[b] <= 0
+                        or int(nxt[b]) == scfg.eos_id
+                        or self.slot_pos[b] >= scfg.max_len):
+                    self.finish(b)
 
 
 # ---------------------------------------------------------------------------
@@ -350,29 +400,7 @@ class BucketScheduler(Scheduler):
             eng.params, eng.caches, toks, step, sub)
         if faults.fire("logits.nan", op="decode", path="bucket"):
             last_logits = last_logits.at[live[0]].set(jnp.nan)
-        fin = None
-        if eng.scfg.numeric_guard:
-            fin = np.asarray(jnp.all(jnp.isfinite(last_logits), axis=-1))
-        nxt = np.asarray(nxt)
-        if self.logit_trace is not None:
-            lg = np.asarray(last_logits)
-            for b in live:
-                self.trace(self.slot_uid[b], lg[b])
-        for b in live:
-            if fin is not None and not fin[b]:
-                # Poisoned logits: the sampled token is garbage — resolve
-                # the stream instead of emitting NaN-derived tokens.
-                self.finish(b, status="numeric_error")
-                continue
-            self.slot_tokens[b].append(int(nxt[b]))
-            self.last_token[b] = nxt[b]
-            self.slot_pos[b] += 1
-            self.slot_remaining[b] -= 1
-            eng.obs.on_decode_token(self.slot_uid[b])
-            if (self.slot_remaining[b] <= 0
-                    or int(nxt[b]) == eng.scfg.eos_id
-                    or self.slot_pos[b] >= eng.scfg.max_len):
-                self.finish(b)
+        self._emit_decoded(live, nxt, last_logits)
 
 
 # ---------------------------------------------------------------------------
@@ -409,8 +437,9 @@ class ChunkedScheduler(Scheduler):
                 pg.ensure(b, hi)
 
     def _sync(self) -> None:
-        self.eng.caches = paged.sync_page_tables(self.eng.caches,
-                                                 self.pagers)
+        with obs.annotate("sched/page_sync"):
+            self.eng.caches = paged.sync_page_tables(self.eng.caches,
+                                                     self.pagers)
 
     def page_stats(self) -> List[Optional[Dict[str, int]]]:
         return [pg.stats() if pg is not None else None
@@ -420,26 +449,31 @@ class ChunkedScheduler(Scheduler):
 
     def admit_once(self) -> None:
         scfg = self.eng.scfg
-        for b in range(scfg.num_slots):
-            if self.slot_uid[b] != -1:
-                continue
-            req = self._pop_ready()
-            if req is None:
-                break
-            prompt = np.asarray(req.prompt, np.int32).reshape(-1)
-            if len(prompt) >= scfg.max_len:
-                # Needs room to decode at least one token: a definite
-                # "rejected" Result, not an exception out of step().
-                self._reject(req)
-                continue
-            self.eng.obs.on_admit(req.uid)
-            self.slot_uid[b] = req.uid
-            self.slot_req[b] = req
-            self.slot_prompt[b] = prompt
-            self.slot_done[b] = 0
-            self.slot_pos[b] = 0
-            self.slot_tokens[b] = []
-            self.slot_phase[b] = "prefill"
+        with obs.annotate("sched/admit") as span:
+            uids = []
+            for b in range(scfg.num_slots):
+                if self.slot_uid[b] != -1:
+                    continue
+                req = self._pop_ready()
+                if req is None:
+                    break
+                prompt = np.asarray(req.prompt, np.int32).reshape(-1)
+                if len(prompt) >= scfg.max_len:
+                    # Needs room to decode at least one token: a definite
+                    # "rejected" Result, not an exception out of step().
+                    self._reject(req)
+                    continue
+                self.eng.obs.on_admit(req.uid)
+                self.slot_uid[b] = req.uid
+                self.slot_req[b] = req
+                self.slot_prompt[b] = prompt
+                self.slot_done[b] = 0
+                self.slot_pos[b] = 0
+                self.slot_tokens[b] = []
+                self.slot_phase[b] = "prefill"
+                uids.append(req.uid)
+            if uids:
+                span.set_metadata(uids=uids)
         self._prefill_round()
 
     def _prefill_round(self) -> None:
@@ -467,39 +501,48 @@ class ChunkedScheduler(Scheduler):
         if not rows:
             return
         self._sync()
+        with obs.annotate("sched/inputs"):
+            toks_d, step2_d = jnp.asarray(toks), jnp.asarray(step2)
         logits, self.eng.caches = self.eng.chunk_step(
-            self.eng.params, self.eng.caches, jnp.asarray(toks),
-            jnp.asarray(step2))
+            self.eng.params, self.eng.caches, toks_d, step2_d)
         if faults.fire("logits.nan", op="prefill", path="chunked"):
             b0 = rows[0]
             logits = logits.at[b0, int(step2[b0, 1]) - 1].set(jnp.nan)
+        # prompts this chunk completes take their greedy first token
+        # from the last REAL chunk position (matches the bucket path's
+        # argmax): only then does the tick wait for the chunk
+        completing = [b for b in rows if self.slot_done[b] + step2[b, 1]
+                      >= len(self.slot_prompt[b])]
         logits_np = None
-        for b in rows:
-            n = int(step2[b, 1])
-            self.slot_done[b] += n
-            self.eng.obs.on_prefill_tokens(n)
-            plen = len(self.slot_prompt[b])
-            if self.slot_done[b] < plen:
-                continue
-            # prompt fully consumed: greedy first token from the last
-            # REAL chunk position (matches the bucket path's argmax)
-            if logits_np is None:
+        if completing:
+            with obs.annotate("sched/prefill_wait"):
+                logits.block_until_ready()
+            with obs.annotate("sched/logits_pull", bytes=logits.nbytes,
+                              uids=[self.slot_uid[b] for b in completing]):
                 logits_np = np.asarray(logits)
-            if (scfg.numeric_guard
-                    and not np.isfinite(logits_np[b, n - 1]).all()):
-                self.finish(b, status="numeric_error")
-                continue
-            first = int(np.argmax(logits_np[b, n - 1]))
-            self.trace(self.slot_uid[b], logits_np[b, n - 1])
-            self.slot_phase[b] = "decode"
-            self.slot_pos[b] = plen
-            self.slot_remaining[b] = min(self.slot_req[b].max_new_tokens,
-                                         scfg.max_len - plen)
-            self.slot_tokens[b] = [first]
-            self.last_token[b] = first
-            self.eng.obs.on_first_token(self.slot_uid[b])
-            if self.slot_remaining[b] <= 0:
-                self.finish(b)
+        with obs.annotate("sched/emit"):
+            for b in rows:
+                n = int(step2[b, 1])
+                self.slot_done[b] += n
+                self.eng.obs.on_prefill_tokens(n)
+                if b not in completing:
+                    continue
+                if (scfg.numeric_guard
+                        and not np.isfinite(logits_np[b, n - 1]).all()):
+                    self.finish(b, status="numeric_error")
+                    continue
+                first = int(np.argmax(logits_np[b, n - 1]))
+                self.trace(self.slot_uid[b], logits_np[b, n - 1])
+                plen = len(self.slot_prompt[b])
+                self.slot_phase[b] = "decode"
+                self.slot_pos[b] = plen
+                self.slot_remaining[b] = min(
+                    self.slot_req[b].max_new_tokens, scfg.max_len - plen)
+                self.slot_tokens[b] = [first]
+                self.last_token[b] = first
+                self.eng.obs.on_first_token(self.slot_uid[b])
+                if self.slot_remaining[b] <= 0:
+                    self.finish(b)
 
     # ------------------------------------------------------------ decode
 
@@ -523,31 +566,13 @@ class ChunkedScheduler(Scheduler):
         if not rows:
             return
         self._sync()
-        toks = jnp.asarray(np.where(step >= 0, self.last_token, 0)
-                           .astype(np.int32)[:, None])
-        self.eng.key, sub = jax.random.split(self.eng.key)
+        with obs.annotate("sched/inputs"):
+            toks = jnp.asarray(np.where(step >= 0, self.last_token, 0)
+                               .astype(np.int32)[:, None])
+            self.eng.key, sub = jax.random.split(self.eng.key)
+            step = jnp.asarray(step)
         nxt, last_logits, self.eng.caches = self.eng.serve_step(
-            self.eng.params, self.eng.caches, toks, jnp.asarray(step), sub)
+            self.eng.params, self.eng.caches, toks, step, sub)
         if faults.fire("logits.nan", op="decode", path="chunked"):
             last_logits = last_logits.at[rows[0]].set(jnp.nan)
-        fin = None
-        if scfg.numeric_guard:
-            fin = np.asarray(jnp.all(jnp.isfinite(last_logits), axis=-1))
-        nxt = np.asarray(nxt)
-        if self.logit_trace is not None:
-            lg = np.asarray(last_logits)
-            for b in rows:
-                self.trace(self.slot_uid[b], lg[b])
-        for b in rows:
-            if fin is not None and not fin[b]:
-                self.finish(b, status="numeric_error")
-                continue
-            self.slot_tokens[b].append(int(nxt[b]))
-            self.last_token[b] = nxt[b]
-            self.slot_pos[b] += 1
-            self.slot_remaining[b] -= 1
-            self.eng.obs.on_decode_token(self.slot_uid[b])
-            if (self.slot_remaining[b] <= 0
-                    or int(nxt[b]) == scfg.eos_id
-                    or self.slot_pos[b] >= scfg.max_len):
-                self.finish(b)
+        self._emit_decoded(rows, nxt, last_logits)

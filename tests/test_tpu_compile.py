@@ -153,3 +153,106 @@ def test_k_sharded_qmm_compiles_on_four_v5e_chips(topo, backend):
     assert "all-reduce" in text
     if backend == "pallas":
         assert "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# Named scopes (``qmm[<mode>]``, ``qconv[<mode>]``, ``kv_page_view``) are
+# metadata only: the program compiled with them is the program compiled
+# without them once each instruction's metadata and the source tables
+# are stripped.
+
+
+def _without_metadata(text):
+    text = re.sub(r", metadata=\{[^{}]*\}", "", text)
+    # keep the module's header line, drop the source tables after it
+    return text.split("\n", 1)[0] + text[text.index("\n%"):]
+
+
+def _compiled_both_ways(monkeypatch, fn, *args):
+    import contextlib
+
+    def compiled():     # a new function each time: jit traces afresh
+        return jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+
+    scoped = compiled()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        plain = compiled()
+    return scoped, plain
+
+
+def test_qmm_scope_is_metadata_only(one_chip, monkeypatch):
+    x = _struct((8, K), jnp.float32, one_chip)
+    qt = _packed_struct(QuantMode.TNN, one_chip)
+    scoped, plain = _compiled_both_ways(
+        monkeypatch, lambda a, q: ops.qmm(a, q, backend="xla"), x, qt)
+    assert 'qmm[tnn]/' in scoped and "qmm[" not in plain
+    assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+def test_qconv_scope_is_metadata_only(one_chip, monkeypatch):
+    # the CNN cell's conv 2: batch 1024, 32x32, 128 -> 128 channels, tnn
+    from repro.core.conv import pack_conv_filters
+
+    w = jax.ShapeDtypeStruct((3, 3, 128, 128), jnp.float32)
+    qt = jax.eval_shape(lambda w: pack_conv_filters(w, QuantMode.TNN), w)
+    qt = jax.tree.map(lambda l: _struct(l.shape, l.dtype, one_chip), qt)
+    x = _struct((1024, 32, 32, 128), jnp.float32, one_chip)
+    scoped, plain = _compiled_both_ways(
+        monkeypatch, lambda a, q: ops.qconv(a, q, backend="xla"), x, qt)
+    assert 'qconv[tnn]/' in scoped and "qconv[" not in plain
+    assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+def test_page_view_scope_is_metadata_only(one_chip, monkeypatch):
+    # the LM cell's cache: 32 slots x 2048 positions in 16-token pages,
+    # 8 KV heads of 128 packed into 4 words a plane
+    from repro.models import paged_kvcache as paged
+
+    slots, npp, page, kvp, dh = 32, 2048 // 16, 16, 8, 128
+    n_pages = 1 + slots * npp
+    words = (n_pages, page, kvp, dh // 32)
+    entry = {"pos": _struct((n_pages, page), jnp.int32, one_chip),
+             "page_table": _struct((slots, npp), jnp.int32, one_chip)}
+    for name in ("k_plus", "k_minus", "v_plus", "v_minus"):
+        entry[name] = _struct(words, jnp.uint32, one_chip)
+    for name in ("k_scale", "v_scale"):
+        entry[name] = _struct((n_pages, page), jnp.float32, one_chip)
+    scoped, plain = _compiled_both_ways(
+        monkeypatch, lambda e: paged.page_view(e, dh), entry)
+    assert "kv_page_view/" in scoped and "kv_page_view" not in plain
+    assert _without_metadata(scoped) == _without_metadata(plain)
+
+
+def _renamed(text):
+    """Instruction names numbered in order of appearance."""
+    names = {}
+    return re.sub(r"%([\w.\-]+)",
+                  lambda m: names.setdefault(m.group(1), f"%i{len(names)}"),
+                  _without_metadata(text))
+
+
+def test_decode_step_scopes_rename_only(one_chip, monkeypatch):
+    # A whole decode step over the 2-bit paged cache: the scopes may
+    # renumber instruction names, and change nothing else.
+    from repro.configs import get_smoke
+    from repro.models import model as model_mod
+    from repro.models.common import ShardLayout
+    from repro.models.kvcache import init_caches
+    from repro.serving.engine import make_serve_step
+
+    cfg = get_smoke("tinyllama-1.1b").with_(kv_cache_dtype="tnn2")
+    layout = ShardLayout(tp=1)
+    key = jax.random.PRNGKey(0)
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _struct(a.shape, a.dtype, one_chip), t)
+    params = on_chip(jax.eval_shape(
+        lambda k: model_mod.init_lm(k, cfg, layout), key))
+    caches = on_chip(jax.eval_shape(
+        lambda: init_caches(cfg, layout, 4, 128, page_size=16)))
+    args = (params, caches, _struct((4, 1), jnp.int32, one_chip),
+            _struct((4,), jnp.int32, one_chip), on_chip(key))
+    scoped, plain = _compiled_both_ways(
+        monkeypatch, make_serve_step(cfg, layout), *args)
+    assert "kv_page_view/" in scoped and "kv_page_view" not in plain
+    assert _renamed(scoped) == _renamed(plain)
