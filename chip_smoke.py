@@ -1,6 +1,7 @@
 """Chip smoke test: the packed low-bit serving path on a TPU, end to end.
 
-    python3 chip_smoke.py              # one chip: device, kernels, engine, cnn
+    python3 chip_smoke.py              # one chip: device, kernels, paged, engine, cnn
+    python3 chip_smoke.py --only paged # one chip: device and the named phases
     python3 chip_smoke.py --chips 4    # four chips: mesh engine vs one device
 
 Phases (one process, seeded, no downloads; each prints its own lines):
@@ -10,6 +11,10 @@ Phases (one process, seeded, no downloads; each prints its own lines):
   dense/indexed, int8/int4 x xla/pallas), compiled, at m in {8, 256},
   k=2048, n=5632; each output must be ``array_equal`` with the
   materializing oracle (the int8/int4 reference is their xla cell);
+* paged   — the paged attention kernel over the 2-bit pages at the LM
+  cell's widths (32 slots of 2048 positions, 8 kv heads of 128) and at
+  tinyllama's (4 kv heads of 64) against ``page_view`` and f32 einsums,
+  S=1 and S=32 (max abs error <= 1e-4);
 * engine  — tinyllama-1.1b at full published width, packed ternary
   weights and the 2-bit paged KV cache, served through ``Engine.submit``
   / ``run`` by a ``tnn`` (XLA popcount) and a ``tnn_dense`` (Pallas MXU)
@@ -48,6 +53,7 @@ ARCH = "tinyllama-1.1b"
 NUM_SLOTS, MAX_LEN = 8, 512
 N_REQUESTS, PROMPT_LEN, NEW_TOKENS = 8, (32, 256), 32
 CNN_BATCH, CNN_TOL = 256, 1e-4
+PAGED_SLOTS, PAGED_MAX_LEN, PAGED_TOL = 32, 2048, 1e-4
 
 
 def log(phase: str, msg: str) -> None:
@@ -168,6 +174,89 @@ def lm_setup(policy: str, **cut):
     return cfg, params
 
 
+def phase_paged(slots=PAGED_SLOTS, max_len=PAGED_MAX_LEN, device=""):
+    """The paged attention kernel against ``page_view`` and f32 einsums
+    at the highest matmul precision, at S=1 and S=32, with dead rows and
+    slots of 1 to ``max_len`` positions, for two geometries of 16-token
+    pages: the LM cell's widths (8 kv heads of 128, 8 query heads each:
+    one page a 128-lane row) and tinyllama's (4 kv heads of 64, 8 query
+    heads each: two pages a row, rolled by a shift from the page table);
+    times both."""
+    for name, kvp, dh in (("lm", 8, 128), ("tinyllama", 4, 64)):
+        _paged_case(name, kvp, dh, slots, max_len, device)
+
+
+def _paged_case(name, kvp, dh, slots, max_len, device):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.kernels.paged_attention import paged_attention
+    from repro.models import paged_kvcache as paged
+    from repro.models.attention import attend_page_view
+    from repro.models.common import ModelConfig, ShardLayout
+
+    g = 8
+    cfg = ModelConfig(name="paged", family="dense", num_layers=1,
+                      d_model=kvp * g * dh, num_heads=kvp * g,
+                      num_kv_heads=kvp, d_ff=1, vocab_size=1,
+                      layer_pattern=(("A", "D"),))
+    page = 16
+    rng = np.random.default_rng(SEED)
+    lens = rng.integers(1, max_len + 1, slots)
+    lens[:3] = (0, 1, max_len)
+    entry = {k: v[0] for k, v in paged.init_paged_caches(
+        cfg, ShardLayout(tp=1), slots, max_len, page_size=page)[0].items()}
+    pager = paged.EntryPager.from_entry(entry, slots)
+    for b, n in enumerate(lens):
+        pager.ensure(b, int(n))
+    entry["page_table"] = pager.device_table(1)[0]
+    append = jax.jit(paged.append_tokens)
+    key = jax.random.PRNGKey(SEED)
+    for start in range(0, max_len, 256):
+        pos = jnp.broadcast_to(start + jnp.arange(256, dtype=jnp.int32),
+                               (slots, 256))
+        kk, kv = jax.random.split(jax.random.fold_in(key, start))
+        shape = (slots, 256, kvp, dh)
+        entry = append(entry, jax.random.normal(kk, shape),
+                       jax.random.normal(kv, shape), pos,
+                       pos < jnp.asarray(lens)[:, None])
+
+    def timed(fn, *args):
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = jax.block_until_ready(fn(*args))
+        return np.asarray(out), (time.perf_counter() - t0) / 5 * 1e3
+
+    def reference(q, e, positions):
+        with jax.default_matmul_precision("highest"):
+            return attend_page_view(q, e, positions)
+
+    kernel, ref = jax.jit(paged_attention), jax.jit(reference)
+    bad = []
+    for s in (1, 32):
+        q = jax.random.normal(jax.random.fold_in(key, s),
+                              (slots, s, kvp, g, dh)).astype(jnp.bfloat16)
+        p0 = np.maximum(lens - s, 0).astype(np.int32)
+        nv = np.minimum(lens, s).astype(np.int32)
+        positions = jnp.asarray(p0)[:, None] + jnp.arange(s)[None]
+        got, t_kernel = timed(kernel, q, entry, jnp.asarray(p0),
+                              jnp.asarray(nv))
+        want, t_view = timed(ref, q.astype(jnp.float32), entry, positions)
+        live = nv > 0
+        err = float(np.abs(got[live] - want[live]).max())
+        dead = bool((got[~live] == 0).all())
+        log("paged", f"{name} S={s} slots={slots} live pages "
+            f"{int(np.sum(-(-lens // page)))} of {slots * pager.npp}: "
+            f"|kernel - f32 view| max {err:.2e} (limit {PAGED_TOL:g}), dead "
+            f"rows 0: {dead}; kernel {t_kernel:.3f} ms, view + einsums "
+            f"{t_view:.3f} ms ({device})")
+        if not (err <= PAGED_TOL and dead):
+            bad.append(f"{name} S={s}")
+    assert not bad, f"paged attention kernel off the reference: {bad}"
+
+
 def phase_engine(device="", cut=None, **serve_kw):
     cfg, params = lm_setup("tnn", **(cut or {}))
     prompts = make_requests(cfg.vocab_size,
@@ -260,6 +349,9 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
                     help="4: run only the mesh engine against one device")
+    ap.add_argument("--only", action="append", default=None,
+                    choices=("kernels", "paged", "engine", "cnn"),
+                    help="run only these one-chip phases (repeatable)")
     args = ap.parse_args(argv)
 
     import jax
@@ -294,8 +386,10 @@ def main(argv=None) -> int:
         f"count={len(devs)} jax={jax.__version__} cache={cache_dir}")
 
     phases = ([("mesh", phase_mesh)] if args.chips == 4 else
-              [("kernels", phase_kernels), ("engine", phase_engine),
-               ("cnn", phase_cnn)])
+              [(name, fn) for name, fn in (
+                  ("kernels", phase_kernels), ("paged", phase_paged),
+                  ("engine", phase_engine), ("cnn", phase_cnn))
+               if args.only is None or name in args.only])
     failed = []
     for name, fn in phases:
         t0 = time.perf_counter()

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from repro.core.policy import QuantPolicy
 from repro.kernels import ops
 from repro.kernels.ops import QuantMode
+from repro.kernels.paged_attention import paged_attention
 from repro.kernels.qtensor import QTensor
 from repro.models.common import (
     ModelConfig, ShardLayout, apply_rope, ceil_to, rms_norm, softcap,
@@ -41,7 +42,8 @@ from repro.models.common import (
 from repro.parallel import sharding
 
 __all__ = ["HeadLayout", "head_layout", "init_attention", "attention",
-           "decode_attention", "paged_attention_step", "project"]
+           "decode_attention", "paged_attention_step", "attend_page_view",
+           "project"]
 
 
 # ---------------------------------------------------------------------------
@@ -354,7 +356,7 @@ def decode_attention(params, x, cfg: ModelConfig, layout: ShardLayout,
 
 
 # ---------------------------------------------------------------------------
-# Paged (ternary) cache: chunked-prefill / decode step against page views
+# Paged (ternary) cache: chunked-prefill / decode step against the pages
 # ---------------------------------------------------------------------------
 
 def paged_attention_step(params, x, cfg: ModelConfig, layout: ShardLayout,
@@ -374,7 +376,10 @@ def paged_attention_step(params, x, cfg: ModelConfig, layout: ShardLayout,
 
     Dead/padding tokens scatter into the reserved scratch page with
     ``INVALID_POS``, so one static-shape call serves rows in different
-    lifecycle phases without corrupting any live page.
+    lifecycle phases without corrupting any live page.  Entries with
+    packed planes attend through ``kernels/paged_attention.py`` (only
+    live pages read, decoded in VMEM); oracle entries through
+    :func:`attend_page_view`.  Both under the scope ``kv_page_view``.
     """
     from repro.models import paged_kvcache as paged
     b, s, d = x.shape
@@ -393,18 +398,36 @@ def paged_attention_step(params, x, cfg: ModelConfig, layout: ShardLayout,
     live = offs[None, :] < nvalid[:, None]
     q, k, v = _qkv(params, x, cfg, hl, jnp.where(live, positions, 0), policy)
     entry = paged.append_tokens(entry, k, v, positions, live)
-    kd, vd, pos_k = paged.page_view(entry, dh)                 # (B,L,KVp,dh)
-
     qg = q.reshape(b, s, hl.kvp, hl.g, dh)
+    if paged.is_packed(entry):
+        # packed planes: the fused kernel reads only live pages
+        with jax.named_scope("kv_page_view"):
+            out = paged_attention(qg, entry, p0, nvalid, window=window,
+                                  cap=cfg.attn_logit_softcap)
+    else:
+        out = attend_page_view(qg, entry, positions, window=window,
+                               cap=cfg.attn_logit_softcap)
+    out = out.reshape(b, s, hl.hp * dh).astype(x.dtype)
+    y = project(params["wo"], out, policy.attn_proj, policy.backend_for("attn_proj"))
+    return y, entry
+
+
+def attend_page_view(qg: jnp.ndarray, entry: Dict[str, jnp.ndarray],
+                     positions: jnp.ndarray, *, window: int = 0,
+                     cap: float = 0.0) -> jnp.ndarray:
+    """qg (B,S,KVp,G,dh) at ``positions`` (B,S) against the dense
+    ``page_view`` of every table entry, scores and mix in f32 einsums ->
+    (B,S,KVp,G,dh) f32.  The oracle (bf16 pages) entries' attention, and
+    the reference of ``kernels/paged_attention.py`` on packed entries."""
+    from repro.models import paged_kvcache as paged
+    kvp, dh = qg.shape[2], qg.shape[-1]
+    kd, vd, pos_k = paged.page_view(entry, kvp, dh)            # (B,L,KVp,dh)
     scores = jnp.einsum("bskgd,blkd->bkgsl", qg.astype(jnp.float32),
                         kd.astype(jnp.float32)) * (dh ** -0.5)
-    scores = softcap(scores, cfg.attn_logit_softcap)
+    scores = softcap(scores, cap)
     valid = pos_k[:, None, :] <= positions[:, :, None]         # (B, S, L)
     if window:
         valid &= (positions[:, :, None] - pos_k[:, None, :]) < window
     scores = jnp.where(valid[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgsl,blkd->bskgd", probs, vd.astype(jnp.float32))
-    out = out.reshape(b, s, hl.hp * dh).astype(x.dtype)
-    y = project(params["wo"], out, policy.attn_proj, policy.backend_for("attn_proj"))
-    return y, entry
+    return jnp.einsum("bkgsl,blkd->bskgd", probs, vd.astype(jnp.float32))

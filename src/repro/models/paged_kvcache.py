@@ -18,13 +18,23 @@ stripped by the layer scan exactly like the dense cache):
 
 * packed (``kv_cache_dtype="tnn2"``)::
 
-      k_plus/k_minus/v_plus/v_minus  (P, n_pages, page, KVp, dw)  uint32
-      k_scale/v_scale                (P, n_pages, page)           f32
-      pos                            (P, n_pages, page)  int32 = INVALID
-      page_table                     (P, B, npp)         int32 = 0
+      k_planes/v_planes  (P, n_rows, groups, 2 * dw, R)  uint32
+      k_scale/v_scale    (P, n_pages, page)                f32
+      pos                (P, n_pages, page)       int32 = INVALID
+      page_table         (P, B, npp)              int32 = 0
 
-  with ``dw = packed_width(head_dim)``; scales live in page metadata
-  (one f32 row per page — the "per-page scale table");
+  with ``dw = packed_width(head_dim)``: a page's plane words are rows of
+  ``R`` lanes (whole 128s) per group of kv heads — rows the ``dw`` plus
+  words then the ``dw`` minus words, lanes (kv head, token) — so the
+  attention kernel's page copies are tile-aligned.  A page takes ``w``
+  lanes of ``kh * page`` (``kernels.paged_attention.page_lanes``, kh =
+  KVp / groups) and ``R // w`` pages share a row where ``w`` < 128:
+  page ``p`` is row ``p // ppr`` at lanes ``(p % ppr) * w``.  At 8 kv
+  heads of 128 and 16-token pages a page is exactly one (8, 128)
+  uint32 tile.  ``groups`` is the number of shards of a ``kv_heads``
+  split under the mesh the caches are built in (1 outside a mesh).
+  Scales live in page metadata (one f32 row per page — the "per-page
+  scale table");
 
 * oracle (``"tnn2-oracle"``): same indirection with dense bf16
   ``k``/``v`` pages — bit-comparable reference for the page/table/mask
@@ -46,8 +56,9 @@ write-then-attend chunk writes all its tokens before attending, so any
 key inside the window of *any* query of the chunk must survive the
 chunk's own ring overwrites (see docs/serving.md).
 
-Sharding: page payloads shard the KVp axis on "kv_heads" and replicate
-word/page axes — the word axes carry packed planes exactly like the
+Sharding: page payloads shard their kv-head ``groups`` axis on
+"kv_heads" — the split they were built for, which the attention
+kernel's shard_map follows — and replicate word/page axes, like the
 QTensor payload planes of parallel/qmm_mesh.py, which replicate plane
 words within a shard and split only head/feature dims.
 """
@@ -61,12 +72,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.encoding import pack_ternary, packed_width, unpack_bits
+from repro.kernels.paged_attention import page_lanes
 from repro.models.common import ModelConfig, ShardLayout
+from repro.parallel import sharding
 from repro.resilience import faults
 
 __all__ = [
-    "INVALID_POS", "SCRATCH_PAGE", "is_paged", "entry_geometry",
-    "init_paged_caches", "paged_logical_axes", "ternarize_tokens",
+    "INVALID_POS", "SCRATCH_PAGE", "is_paged", "is_packed", "entry_geometry",
+    "head_groups", "init_paged_caches", "paged_logical_axes", "ternarize_tokens",
     "append_tokens", "page_view", "PageAllocator", "PagePoolExhausted",
     "EntryPager", "make_pagers", "sync_page_tables", "reset_pages",
     "tree_nbytes",
@@ -92,12 +105,28 @@ def is_paged(entry: Any) -> bool:
     return isinstance(entry, dict) and "page_table" in entry
 
 
+def is_packed(entry: Any) -> bool:
+    """True for a paged entry with packed ternary planes (not oracle)."""
+    return is_paged(entry) and "k_planes" in entry
+
+
 def entry_geometry(entry) -> Tuple[int, int, int]:
     """(n_pages, page, npp) from leaf shapes — valid with or without the
     leading period dim (the layer scan strips it)."""
     npp = entry["page_table"].shape[-1]
     n_pages, page = entry["pos"].shape[-2:]
     return n_pages, page, npp
+
+
+def head_groups(kvp: int) -> int:
+    """Groups of the ``kvp`` kv heads a packed pool keeps apart: the
+    shards of the active mesh's ``kv_heads`` split (1 outside a mesh)."""
+    ctx = sharding.active()
+    if ctx is None:
+        return 1
+    axes = sharding.spec_for((kvp,), ("kv_heads",), ctx)[0]
+    axes = () if axes is None else (axes,) if isinstance(axes, str) else axes
+    return int(np.prod([ctx.axis_sizes[a] for a in axes], initial=1))
 
 
 # ---------------------------------------------------------------------------
@@ -139,9 +168,11 @@ def init_paged_caches(cfg: ModelConfig, layout: ShardLayout, batch: int,
             entry["k"] = jnp.zeros(shape, jnp.bfloat16)
             entry["v"] = jnp.zeros(shape, jnp.bfloat16)
         else:
-            wshape = (p_dim, n_pages, page_size, hl.kvp, dw)
-            for name in ("k_plus", "k_minus", "v_plus", "v_minus"):
-                entry[name] = jnp.zeros(wshape, jnp.uint32)
+            groups = head_groups(hl.kvp)
+            w, ppr = page_lanes(hl.kvp // groups, page_size)
+            wshape = (p_dim, -(-n_pages // ppr), groups, 2 * dw, w * ppr)
+            entry["k_planes"] = jnp.zeros(wshape, jnp.uint32)
+            entry["v_planes"] = jnp.zeros(wshape, jnp.uint32)
             entry["k_scale"] = jnp.zeros((p_dim, n_pages, page_size),
                                          jnp.float32)
             entry["v_scale"] = jnp.zeros((p_dim, n_pages, page_size),
@@ -157,10 +188,9 @@ def paged_logical_axes(cfg: ModelConfig) -> List[Dict[str, Any]]:
         "page_table": (None, "batch", None),
         "k": (None, None, None, "kv_heads", None),
         "v": (None, None, None, "kv_heads", None),
-        "k_plus": (None, None, None, "kv_heads", None),
-        "k_minus": (None, None, None, "kv_heads", None),
-        "v_plus": (None, None, None, "kv_heads", None),
-        "v_minus": (None, None, None, "kv_heads", None),
+        # (period, row, kv-head group, word, lane)
+        "k_planes": (None, None, "kv_heads", None, None),
+        "v_planes": (None, None, "kv_heads", None, None),
         "k_scale": (None, None, None),
         "v_scale": (None, None, None),
     }
@@ -215,13 +245,18 @@ def append_tokens(entry: Dict[str, Any], k: jnp.ndarray, v: jnp.ndarray,
     out = dict(entry)
     out["pos"] = entry["pos"].at[pid, off].set(
         jnp.where(live, pos32, INVALID_POS))
-    if "k_plus" in entry:
+    if is_packed(entry):
+        kvp, groups = k.shape[-2], entry["k_planes"].shape[-3]
+        kh = kvp // groups
+        w, ppr = page_lanes(kh, page)
+        head = jnp.arange(kvp, dtype=jnp.int32)
+        row = (pid // ppr)[..., None]
+        lane = ((pid % ppr) * w + off)[..., None] + (head % kh) * page
         for name, val in (("k", k), ("v", v)):
             t, alpha = ternarize_tokens(val)
-            plus, minus = pack_ternary(t)
-            out[f"{name}_plus"] = entry[f"{name}_plus"].at[pid, off].set(plus)
-            out[f"{name}_minus"] = (
-                entry[f"{name}_minus"].at[pid, off].set(minus))
+            words = jnp.concatenate(pack_ternary(t), axis=-1)  # (B,S,KVp,2dw)
+            out[f"{name}_planes"] = entry[f"{name}_planes"].at[
+                row, head // kh, :, lane].set(words)
             out[f"{name}_scale"] = (
                 entry[f"{name}_scale"].at[pid, off].set(alpha))
     else:
@@ -230,40 +265,48 @@ def append_tokens(entry: Dict[str, Any], k: jnp.ndarray, v: jnp.ndarray,
     return out
 
 
-def page_view(entry: Dict[str, Any], dh: int
+def page_view(entry: Dict[str, Any], kvp: int, dh: int
               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Dense per-slot gather view for attention reads (in-trace).
+    """Dense per-slot gather view of every table entry (in-trace).
 
     -> (k, v, pos): k/v (B, L_cap, KVp, dh), pos (B, L_cap) with
-    ``L_cap = npp * page``.  Packed entries stream plane WORDS from HBM
-    and decode in-register — ``unpack_bits(plus) - unpack_bits(minus)``
-    times the per-token scale, the same shift/mask idiom as
-    ``dense_fused._unpack_bits`` and the eq. (2) correction with zero
-    bias (pad bits encode (0,0) = exact 0, so no depth correction is
-    needed).  Unallocated logical pages resolve to the scratch page,
-    whose positions stay ``INVALID_POS`` and fail every ``pos <= step``
-    mask.  Its ops carry the scope ``kv_page_view`` (metadata only).
+    ``L_cap = npp * page``; ``kvp`` is the entry's kv heads (a packed
+    pool's shape does not give them).  The oracle entries' attention reads it;
+    packed entries are read by ``kernels/paged_attention.py`` instead,
+    and their view — ``unpack_bits(plus) - unpack_bits(minus)`` times
+    the per-token scale, pad bits (0,0) = exact 0 — is the kernel's
+    reference in the tests.  Unallocated logical pages resolve to the
+    scratch page, whose positions stay ``INVALID_POS`` and fail every
+    ``pos <= step`` mask.  Its ops carry the scope ``kv_page_view``
+    (metadata only).
     """
     with jax.named_scope("kv_page_view"):
-        return _page_view(entry, dh)
+        return _page_view(entry, kvp, dh)
 
 
-def _page_view(entry, dh):
+def _page_view(entry, kvp, dh):
     n_pages, page, npp = entry_geometry(entry)
     table = entry["page_table"]                    # (B, npp)
     b = table.shape[0]
     pos = entry["pos"][table].reshape(b, npp * page)
-    if "k_plus" in entry:
+    if is_packed(entry):
+        groups, rows = entry["k_planes"].shape[-3:-1]
+        kh, dw = kvp // groups, rows // 2
+        w, ppr = page_lanes(kh, page)
+        lane = (table % ppr)[..., None] * w + jnp.arange(kh * page)
+
         def dec(name):
-            val = (unpack_bits(entry[f"{name}_plus"][table], dh)
-                   - unpack_bits(entry[f"{name}_minus"][table], dh)
-                   ).astype(jnp.float32)
+            x = entry[f"{name}_planes"][table // ppr]  # (B,npp,grp,2dw,R)
+            x = jnp.take_along_axis(x, lane[:, :, None, None, :], axis=-1)
+            x = x.reshape(b, npp, groups, rows, kh, page).transpose(
+                0, 1, 5, 2, 4, 3).reshape(b, npp, page, kvp, rows)
+            val = (unpack_bits(x[..., :dw], dh)
+                   - unpack_bits(x[..., dw:], dh)).astype(jnp.float32)
             scale = entry[f"{name}_scale"][table]
             return (val * scale[..., None, None]).reshape(
                 b, npp * page, val.shape[-2], dh)
         k, v = dec("k"), dec("v")
     else:
-        kvp = entry["k"].shape[-2]
         k = entry["k"][table].reshape(b, npp * page, kvp, dh)
         v = entry["v"][table].reshape(b, npp * page, kvp, dh)
     return k, v, pos
@@ -358,6 +401,12 @@ class EntryPager:
             self.alloc.free(pids)
             self.dirty = True
         return pids
+
+    def live_pages(self, lengths: np.ndarray) -> int:
+        """Pages the paged attention kernel reads for slots whose live
+        lengths are ``lengths`` (0: a dead row), summed over slots."""
+        n = -(-np.asarray(lengths, np.int64) // self.page)
+        return int(np.minimum(n, self.npp).sum())
 
     def device_table(self, num_periods: int) -> jnp.ndarray:
         self.dirty = False

@@ -441,6 +441,21 @@ class ChunkedScheduler(Scheduler):
             self.eng.caches = paged.sync_page_tables(self.eng.caches,
                                                      self.pagers)
 
+    def _kv_pages(self, lengths: np.ndarray) -> Dict[str, int]:
+        """Span stats of a step's page reads: the pages the packed
+        entries' attention kernel reads for these live lengths
+        (``kv_pages_live``) and the pages a dense view of every table
+        entry would decode (``kv_pages_view``), summed over slots and
+        entries.  Empty with obs off or no packed entry."""
+        if not obs.obs_enabled():
+            return {}
+        live = view = 0
+        for entry, pg in zip(self.eng.caches, self.pagers):
+            if pg is not None and paged.is_packed(entry):
+                live += pg.live_pages(lengths)
+                view += len(lengths) * pg.npp
+        return {"kv_pages_live": live, "kv_pages_view": view} if view else {}
+
     def page_stats(self) -> List[Optional[Dict[str, int]]]:
         return [pg.stats() if pg is not None else None
                 for pg in self.pagers]
@@ -501,7 +516,8 @@ class ChunkedScheduler(Scheduler):
         if not rows:
             return
         self._sync()
-        with obs.annotate("sched/inputs"):
+        pages = self._kv_pages(np.where(step2[:, 1] > 0, step2.sum(1), 0))
+        with obs.annotate("sched/inputs", step="chunk", **pages):
             toks_d, step2_d = jnp.asarray(toks), jnp.asarray(step2)
         logits, self.eng.caches = self.eng.chunk_step(
             self.eng.params, self.eng.caches, toks_d, step2_d)
@@ -566,7 +582,8 @@ class ChunkedScheduler(Scheduler):
         if not rows:
             return
         self._sync()
-        with obs.annotate("sched/inputs"):
+        pages = self._kv_pages(np.where(step >= 0, step + 1, 0))
+        with obs.annotate("sched/inputs", step="decode", **pages):
             toks = jnp.asarray(np.where(step >= 0, self.last_token, 0)
                                .astype(np.int32)[:, None])
             self.eng.key, sub = jax.random.split(self.eng.key)

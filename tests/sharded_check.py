@@ -21,7 +21,10 @@ Checks (each entry in the report is "ok" or an error string):
   single-device engine, its watchdog flags a silent device, and
   ``rebuild_after_loss`` re-packs onto the surviving (1, 4) mesh with
   identical decode output;
-* the mesh bodies trace once per (mode, shape) — no per-call retrace.
+* the mesh bodies trace once per (mode, shape) — no per-call retrace;
+* the packed paged cache's attention kernel runs per shard (kv heads
+  over "model", slots over "data") with the single-device output, and a
+  tnn2 mesh engine decodes the single-device tokens.
 """
 
 import json
@@ -257,6 +260,73 @@ def _engine_inflight():
         assert r.tokens == single[uid], uid
     eng.close()
     eng2.close()
+
+
+@check("paged_attention_sharded")
+def _paged_attention():
+    # The packed paged cache's attention kernel under the low-bit serving
+    # rules: 8 kv heads of 16-token pages, the cache built on the mesh so
+    # its kv heads come in 4 groups over "model" (2 heads, 32 lanes a
+    # page, 4 pages a plane row), slots 2 ways over "data", in a
+    # shard_map that splits the pool as its sharding does (no gather);
+    # the output of page_view + f32 einsums on one device.  And a tnn2
+    # engine whose 2 kv heads cannot split decodes the single-device
+    # tokens on the mesh.
+    from repro.kernels.paged_attention import paged_attention
+    from repro.models import paged_kvcache as paged
+    from repro.models.attention import attend_page_view
+
+    cfg = get_smoke("tinyllama-1.1b").with_(num_heads=8, num_kv_heads=8)
+    layout = ShardLayout(tp=1)
+    b, s, page, dh = 4, 8, 16, cfg.head_dim_
+    rules = sharding.RULESETS["serve_lowbit"]
+    with sharding.use_mesh(_mesh(), rules):
+        entry = {k: v[0] for k, v in paged.init_paged_caches(
+            cfg, layout, b, 32, page_size=page, prefill_chunk=s)[0].items()}
+    assert entry["k_planes"].shape[1:] == (4, 2, 128), entry["k_planes"].shape
+    pager = paged.EntryPager.from_entry(entry, b)
+    lens = [8, 3, 0, 6]
+    for slot, n in enumerate(lens):
+        pager.ensure(slot, n)
+    entry["page_table"] = pager.device_table(1)[0]
+    key = jax.random.PRNGKey(3)
+    kv = jax.random.normal(key, (b, s, 8, dh))
+    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+    entry = jax.jit(paged.append_tokens)(entry, kv, -kv, pos,
+                                         pos < jnp.asarray(lens)[:, None])
+    q = jax.random.normal(jax.random.fold_in(key, 1), (b, s, 8, 1, dh))
+    q = q.astype(jnp.bfloat16).astype(jnp.float32)
+    p0 = jnp.zeros((b,), jnp.int32)
+    nv = jnp.asarray(lens, jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(attend_page_view)(
+            q, entry, jnp.broadcast_to(jnp.arange(s), (b, s))))
+    with sharding.use_mesh(_mesh(), rules):
+        fn = jax.jit(paged_attention)
+        jaxpr = str(jax.make_jaxpr(fn)(q, entry, p0, nv))
+        assert "shard_map" in jaxpr and "f32[2,8,2,1,8]" in jaxpr
+        axes = paged.paged_logical_axes(cfg)[0]
+        placed = {k: jax.device_put(v, sharding.named_sharding(
+            v.shape, axes[k][1:])) for k, v in entry.items()}
+        compiled = fn.lower(q, placed, p0, nv).compile()
+        assert "all-gather" not in compiled.as_text()
+        got = np.asarray(compiled(q, placed, p0, nv))
+    live = np.asarray(nv) > 0
+    assert np.all(got[~live] == 0)
+    # the bound of tests/test_paged_kvcache.py (KERNEL_ATOL)
+    np.testing.assert_allclose(got[live], want[live], rtol=0, atol=5e-5)
+
+    tcfg = get_smoke("tinyllama-1.1b").with_(kv_cache_dtype="tnn2")
+    params = model_mod.init_lm(key, tcfg, layout)
+    base = dict(num_slots=2, max_len=16, page_size=4, prefill_chunk=4,
+                sampler=SamplerConfig(temperature=0.0))
+    prompts = [[3, 1, 4, 1, 5], [9, 2, 6]]
+    single = _decode(Engine(params, tcfg, layout, ServeConfig(**base),
+                            seed=0), prompts)
+    eng = Engine(params, tcfg, layout, ServeConfig(**base, mesh=_mesh()),
+                 seed=0)
+    assert _decode(eng, prompts) == single, "tnn2 mesh decode diverged"
+    eng.close()
 
 
 def main():

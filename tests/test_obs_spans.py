@@ -86,7 +86,11 @@ def _host_spans(trace_dir):
 
 
 def _traced_tokens(smoke, tmp_path):
-    with jax.profiler.trace(str(tmp_path)):
+    # as the benchmark's capture (bench/benchkit/trace.py): annotations
+    # only, no Python call stacks
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
         tokens = _serve(smoke)
     return tokens, _host_spans(str(tmp_path))
 
@@ -174,5 +178,6 @@ def test_page_view_names_its_ops(smoke):
     cfg, layout, _ = smoke
     entry = jax.tree.map(lambda a: a[0], paged.init_paged_caches(
         cfg, ShardLayout(tp=1), 2, 32, page_size=8)[0])
-    text = _op_names(lambda e: paged.page_view(e, cfg.head_dim_), entry)
+    text = _op_names(
+        lambda e: paged.page_view(e, cfg.num_kv_heads, cfg.head_dim_), entry)
     assert "/kv_page_view/" in text

@@ -14,6 +14,7 @@ Covers the satellite-3 numerics contract:
   exhaustion and double frees raise, release balances to zero.
 """
 
+import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,8 +124,8 @@ def test_oracle_roundtrip_bit_exact(rng):
 
     packed = paged.append_tokens(packed, k, v, positions, live)
     oracle = paged.append_tokens(oracle, k, v, positions, live)
-    kp, vp, pos_p = paged.page_view(packed, dh)
-    ko, vo, pos_o = paged.page_view(oracle, dh)
+    kp, vp, pos_p = paged.page_view(packed, kvp, dh)
+    ko, vo, pos_o = paged.page_view(oracle, kvp, dh)
 
     np.testing.assert_array_equal(np.asarray(pos_p), np.asarray(pos_o))
     written = np.asarray(pos_p[:, :s])
@@ -156,7 +157,7 @@ def test_quantization_error_bounded(rng):
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     live = jnp.ones((b, s), bool)
     packed = paged.append_tokens(packed, k, v, positions, live)
-    kd, vd, _ = paged.page_view(packed, dh)
+    kd, vd, _ = paged.page_view(packed, kvp, dh)
 
     for x, got in ((k, kd[:, :s]), (v, vd[:, :s])):
         t, alpha = paged.ternarize_tokens(x)
@@ -181,14 +182,14 @@ def test_dead_tokens_route_to_scratch(rng):
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     live = jnp.zeros((b, s), bool).at[0].set(True)     # row 1 entirely dead
     out = paged.append_tokens(packed, k, k, positions, live)
-    _, _, pos = paged.page_view(out, dh)
+    _, _, pos = paged.page_view(out, kvp, dh)
     assert np.all(np.asarray(pos[1]) == INVALID_POS)   # dead row untouched
     np.testing.assert_array_equal(np.asarray(pos[0, :s]),
                                   np.asarray(positions[0]))
     # unallocated tables resolve to scratch: a fresh entry's view is all
     # INVALID_POS, so every `pos <= step` mask rejects it
     fresh, _ = _paged_pair(cfg, b, 32, page_size=8)
-    _, _, pos0 = paged.page_view(fresh, dh)
+    _, _, pos0 = paged.page_view(fresh, kvp, dh)
     assert np.all(np.asarray(pos0) == INVALID_POS)
 
 
@@ -321,3 +322,183 @@ def test_tree_nbytes_counts_packed_vs_dense():
     assert paged.tree_nbytes(packed) < paged.tree_nbytes(dense)
     dw = packed_width(cfg.head_dim_)
     assert dw == -(-cfg.head_dim_ // 32)
+
+
+# --------------------------------------------- paged attention kernel
+#
+# kernels/paged_attention.py against ``attend_page_view`` (page_view +
+# f32 einsums, the oracle entries' attention) on the same packed entry.
+# Both see bf16-exact queries and the kernel's dot operands are exact
+# ({0, 1} bits, bf16 queries, alpha_v * p as two bf16 terms), so only
+# the order of f32 sums differs.  The largest difference over the cases
+# below, outputs of scale 0.5-1.5, read 1.1e-6 to 5.5e-6 on three seeds
+# (interpret mode, CPU); one bf16 term for alpha_v * p read 7.1e-4 to
+# 3.6e-3, and a wrong mask, page or scale moves outputs by 0.1 and more.
+KERNEL_ATOL = 5e-5
+
+
+def _kernel(*args, **kw):
+    from repro.kernels.paged_attention import paged_attention
+    return _jitted(paged_attention, ("window", "cap"))(*args, **kw)
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted(fn, static=()):
+    """One jit per function, so a shape the file has seen compiles once."""
+    return jax.jit(fn, static_argnames=static)
+
+
+def _written_entry(cfg, lens, *, max_len, page, chunk, key, entry_idx=0,
+                   extra=0):
+    """Packed entry (no period dim) whose slot ``b`` holds positions
+    ``[0, lens[b])`` of random K/V, appended ``chunk`` tokens at a time;
+    every slot gets pages for ``extra`` positions more than it holds."""
+    from repro.models.attention import head_layout
+    b = len(lens)
+    kvp = head_layout(cfg.num_heads, cfg.num_kv_heads, LAYOUT.tp).kvp
+    entry = _strip_period(paged.init_paged_caches(
+        cfg, LAYOUT, b, max_len, page_size=page,
+        prefill_chunk=chunk)[entry_idx])
+    pager = paged.EntryPager.from_entry(entry, b)
+    append = _jitted(paged.append_tokens)
+    for i, start in enumerate(range(0, max(lens), chunk)):
+        for slot, n in enumerate(lens):
+            if start < n:
+                pager.ensure(slot, min(start + chunk, n))
+        entry["page_table"] = pager.device_table(1)[0]
+        pos = jnp.broadcast_to(start + jnp.arange(chunk, dtype=jnp.int32),
+                               (b, chunk))
+        live = pos < jnp.asarray(lens)[:, None]
+        kk, kv = jax.random.split(jax.random.fold_in(key, i))
+        shape = (b, chunk, kvp, cfg.head_dim_)
+        entry = append(entry, jax.random.normal(kk, shape),
+                       jax.random.normal(kv, shape), pos, live)
+    for slot, n in enumerate(lens):
+        if n:
+            pager.ensure(slot, n + extra)
+    entry["page_table"] = pager.device_table(1)[0]
+    return entry, pager
+
+
+def _queries(cfg, b, s, key):
+    from repro.models.attention import head_layout
+    hl = head_layout(cfg.num_heads, cfg.num_kv_heads, LAYOUT.tp)
+    q = jax.random.normal(key, (b, s, hl.kvp, hl.g, cfg.head_dim_))
+    return q.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+def _rows(lens, s):
+    """(p0, nvalid) of the step that wrote each slot's last tokens: one
+    token (s == 1) or the last chunk of ``s``; 0 tokens for a dead row."""
+    p0 = [max(n - 1, 0) if s == 1 else (max(n - 1, 0) // s) * s for n in lens]
+    nv = [n - p if n else 0 for n, p in zip(lens, p0)]
+    return jnp.asarray(p0, jnp.int32), jnp.asarray(nv, jnp.int32)
+
+
+def _kernel_vs_view(cfg, entry, lens, s, key, window=0):
+    from repro.models.attention import attend_page_view
+    q = _queries(cfg, len(lens), s, key)
+    p0, nv = _rows(lens, s)
+    kw = dict(window=window, cap=cfg.attn_logit_softcap)
+    got = np.asarray(_kernel(q, entry, p0, nv, **kw))
+    positions = p0[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
+    want = np.asarray(_jitted(attend_page_view, ("window", "cap"))(
+        q, entry, positions, **kw))
+    live = np.asarray(nv) > 0
+    assert np.all(got[~live] == 0)                     # dead rows: 0
+    err = np.abs(got[live] - want[live]).max()
+    assert err <= KERNEL_ATOL, err
+    assert np.abs(want[live]).max() > 0.1              # not vacuous
+    return got
+
+
+@pytest.mark.parametrize("s", [1, 8], ids=["decode", "chunk"])
+@pytest.mark.parametrize("lens", [(0, 1, 5, 16, 21), (8, 0, 24, 3, 40)],
+                         ids=["short", "long"])
+def test_kernel_matches_page_view(rng, lens, s):
+    """Dead rows, slots with 0, 1, partial and full pages, at the
+    engine's two shapes (S=1 decode, S=chunk)."""
+    cfg = get_smoke("tinyllama-1.1b")
+    entry, _ = _written_entry(cfg, list(lens), max_len=64, page=8, chunk=8,
+                              key=rng)
+    _kernel_vs_view(cfg, entry, list(lens), s, jax.random.fold_in(rng, 9))
+
+
+@pytest.mark.parametrize("s", [1, 8], ids=["decode", "chunk"])
+def test_kernel_al_ring_past_wraparound(rng, s):
+    """An AL ring (gemma2 smoke: window 64, softcap 50) past wrap-around:
+    the ring holds 72 positions, slots hold 100, 71 and 30."""
+    cfg = get_smoke("gemma2-27b")
+    assert cfg.layer_pattern[0][0] == "AL" and cfg.attn_logit_softcap
+    lens = [100, 71, 30]
+    entry, _ = _written_entry(cfg, lens, max_len=128, page=8, chunk=8,
+                              key=rng)
+    _, page, npp = paged.entry_geometry(entry)
+    assert npp * page < max(lens)
+    _kernel_vs_view(cfg, entry, lens, s, jax.random.fold_in(rng, 9),
+                    window=cfg.sliding_window)
+
+
+def test_kernel_ignores_stale_freed_pages(rng):
+    """A slot's pages, released (positions poisoned by reset_pages, plane
+    words and scales left stale) and handed to another slot that writes
+    fewer tokens: the stale tokens never count."""
+    cfg = get_smoke("tinyllama-1.1b")
+    entry, pager = _written_entry(cfg, [30, 0], max_len=64, page=8,
+                                  chunk=8, key=rng)
+    entry = paged.reset_pages(entry, pager.release(0))
+    from repro.models.attention import head_layout
+    kvp = head_layout(cfg.num_heads, cfg.num_kv_heads, LAYOUT.tp).kvp
+    pager.ensure(1, 11)
+    assert set(pager.owned[1]) <= set(range(1, 5))     # the freed pages
+    entry["page_table"] = pager.device_table(1)[0]
+    pos = jnp.broadcast_to(jnp.arange(16, dtype=jnp.int32), (2, 16))
+    live = jnp.zeros((2, 16), bool).at[1, :11].set(True)
+    kv = jax.random.normal(rng, (2, 16, kvp, cfg.head_dim_))
+    entry = paged.append_tokens(entry, kv, -kv, pos, live)
+    _kernel_vs_view(cfg, entry, [0, 11], 1, jax.random.fold_in(rng, 9))
+
+
+def test_kernel_output_ignores_garbage_past_live_length(rng):
+    """Plane words, scales (NaN) and positions (0) of garbage in every
+    page past a slot's live pages — allocated ahead, unallocated
+    (scratch) — and past its length inside its last live page leave the
+    kernel's output as it was, bit for bit."""
+    from repro.kernels import paged_attention as kernel
+    assert kernel.INVALID_POS == paged.INVALID_POS
+    cfg = get_smoke("tinyllama-1.1b")
+    lens = [0, 1, 5, 16, 21]
+    entry, pager = _written_entry(cfg, lens, max_len=64, page=8, chunk=8,
+                                  key=rng, extra=16)
+    n_pages, page, _ = paged.entry_geometry(entry)
+    used = np.zeros((n_pages, page), bool)             # live (page, token)
+    for slot, n in enumerate(lens):
+        for t in range(n):
+            used[pager.owned[slot][t // page], t % page] = True
+    assert not used[paged.SCRATCH_PAGE].any()
+    assert any(len(o) * page > n + page for o, n in zip(pager.owned, lens)
+               if n)                                   # pages past length
+    from repro.models.attention import head_layout
+    kvp = head_layout(cfg.num_heads, cfg.num_kv_heads, LAYOUT.tp).kvp
+    kh = kvp // entry["k_planes"].shape[1]
+    w, ppr = kernel.page_lanes(kh, page)
+    lane_used = np.zeros(entry["k_planes"].shape, bool)  # (row, grp, word, lane)
+    for p, t in zip(*np.nonzero(used)):
+        for h in range(kvp):
+            lane_used[p // ppr, h // kh, :,
+                      (p % ppr) * w + (h % kh) * page + t] = True
+    bad = dict(entry)
+    keys = jax.random.split(rng, 2)
+    for key, name in zip(keys, ("k", "v")):
+        junk = jax.random.bits(key, entry[f"{name}_planes"].shape, jnp.uint32)
+        bad[f"{name}_planes"] = jnp.where(lane_used, entry[f"{name}_planes"],
+                                          junk)
+        bad[f"{name}_scale"] = jnp.where(used, entry[f"{name}_scale"],
+                                         jnp.nan)
+    bad["pos"] = jnp.where(used, entry["pos"], 0)
+    q = _queries(cfg, len(lens), 1, jax.random.fold_in(rng, 9))
+    p0, nv = _rows(lens, 1)
+    clean = np.asarray(_kernel(q, entry, p0, nv))
+    dirty = np.asarray(_kernel(q, bad, p0, nv))
+    np.testing.assert_array_equal(dirty, clean)
+    _kernel_vs_view(cfg, entry, lens, 1, jax.random.fold_in(rng, 9))

@@ -146,3 +146,11 @@ def test_watchdog_rebuild_migrates_inflight_requests(sharded_report):
     the single-device tokens (docs/resilience.md)."""
     assert sharded_report["watchdog_rebuild_inflight"] == "ok", \
         sharded_report["watchdog_rebuild_inflight"]
+
+
+def test_paged_attention_kernel_shards_over_kv_heads(sharded_report):
+    """The packed paged cache's attention kernel under an 8-device mesh:
+    one kernel per (kv-head, slot) shard, the single-device output, and
+    a tnn2 mesh engine that decodes the single-device tokens."""
+    assert sharded_report["paged_attention_sharded"] == "ok", \
+        sharded_report["paged_attention_sharded"]

@@ -204,22 +204,96 @@ def test_qconv_scope_is_metadata_only(one_chip, monkeypatch):
     assert _without_metadata(scoped) == _without_metadata(plain)
 
 
+def _compile_paged_kernel(monkeypatch):
+    """The paged attention kernel compiles for the chip here too,
+    where the platform decision would interpret it (CPU backend)."""
+    from repro.kernels import paged_attention
+
+    monkeypatch.setattr(paged_attention, "resolve_interpret", lambda i: False)
+
+
+# the LM cell's cache: 32 slots x 2048 positions in 16-token pages, 8 KV
+# heads of 128 packed into 4 words a plane, 8 query heads a KV head
+SLOTS, NPP, PAGE, KVP, G, DH = 32, 2048 // 16, 16, 8, 8, 128
+
+
+def _paged_cfg(kvp, dh):
+    from repro.models.common import ModelConfig
+    return ModelConfig(name="paged", family="dense", num_layers=1,
+                       d_model=kvp * 8 * dh, num_heads=kvp * 8,
+                       num_kv_heads=kvp, d_ff=1, vocab_size=1,
+                       kv_cache_dtype="tnn2", layer_pattern=(("A", "D"),))
+
+
+def _paged_entry(kvp, dh, slots, max_len, page, sharding):
+    """Shape-only packed entry (no period dim), built under the active
+    mesh (if any) so its kv-head groups follow it, on ``sharding``."""
+    from repro.models import paged_kvcache as paged
+    from repro.models.common import ShardLayout
+
+    entry = jax.eval_shape(lambda: {k: v[0] for k, v in paged.init_paged_caches(
+        _paged_cfg(kvp, dh), ShardLayout(tp=1), slots, max_len,
+        page_size=page)[0].items()})
+    return {k: _struct(v.shape, v.dtype, sharding(k, v)) for k, v in
+            entry.items()}
+
+
+@pytest.mark.parametrize("cell,s", [("lm", 1), ("lm", 32), ("tinyllama", 1)],
+                         ids=["decode", "chunk", "tinyllama-decode"])
+def test_paged_attention_kernel_compiles_for_v5e(one_chip, monkeypatch, cell,
+                                                 s):
+    # the LM cell: one (8, 128) uint32 tile a page, copies and decode
+    # tile-aligned; tinyllama (4 kv heads of 64, 16-token pages): two
+    # pages a 128-lane row, each head's tokens rolled by a shift read
+    # from the page table
+    from repro.kernels.paged_attention import paged_attention
+
+    _compile_paged_kernel(monkeypatch)
+    kvp, dh, slots, max_len = ((KVP, DH, SLOTS, NPP * PAGE) if cell == "lm"
+                               else (4, 64, 8, 512))
+    entry = _paged_entry(kvp, dh, slots, max_len, PAGE,
+                         lambda *_: one_chip)
+    q = _struct((slots, s, kvp, G, dh), jnp.bfloat16, one_chip)
+    rows = _struct((slots,), jnp.int32, one_chip)
+    compiled = jax.jit(paged_attention).lower(q, entry, rows, rows).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_attention_on_four_v5e_chips_gathers_no_pages(topo,
+                                                            monkeypatch):
+    # The LM cell's cache built under a 4-way "model" mesh keeps its kv
+    # heads in 4 groups of 2 (32 lanes a page, 4 pages a row), sharded
+    # as paged_logical_axes says; the kernel's shard_map splits them the
+    # same way, so the compiled step moves no page between chips.
+    from repro.kernels.paged_attention import paged_attention
+    from repro.models.kvcache import cache_logical_axes
+    from repro.parallel import sharding
+
+    _compile_paged_kernel(monkeypatch)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    axes = {k: ax[1:] for k, ax in
+            cache_logical_axes(_paged_cfg(KVP, DH))[0].items()}
+    with sharding.use_mesh(mesh, sharding.RULESETS["serve_lowbit"]):
+        entry = _paged_entry(
+            KVP, DH, SLOTS, NPP * PAGE, PAGE, lambda k, v: NamedSharding(
+                mesh, sharding.spec_for(v.shape, axes[k])))
+        assert entry["k_planes"].shape[1:] == (4, 8, 128)
+        assert entry["k_planes"].sharding.spec[1] == "model"
+        q = _struct((SLOTS, 1, KVP, G, DH), jnp.bfloat16,
+                    NamedSharding(mesh, P(None, None, "model")))
+        rows = _struct((SLOTS,), jnp.int32, NamedSharding(mesh, P()))
+        text = jax.jit(paged_attention).lower(
+            q, entry, rows, rows).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text and "all-to-all" not in text
+
+
 def test_page_view_scope_is_metadata_only(one_chip, monkeypatch):
-    # the LM cell's cache: 32 slots x 2048 positions in 16-token pages,
-    # 8 KV heads of 128 packed into 4 words a plane
     from repro.models import paged_kvcache as paged
 
-    slots, npp, page, kvp, dh = 32, 2048 // 16, 16, 8, 128
-    n_pages = 1 + slots * npp
-    words = (n_pages, page, kvp, dh // 32)
-    entry = {"pos": _struct((n_pages, page), jnp.int32, one_chip),
-             "page_table": _struct((slots, npp), jnp.int32, one_chip)}
-    for name in ("k_plus", "k_minus", "v_plus", "v_minus"):
-        entry[name] = _struct(words, jnp.uint32, one_chip)
-    for name in ("k_scale", "v_scale"):
-        entry[name] = _struct((n_pages, page), jnp.float32, one_chip)
+    entry = _paged_entry(KVP, DH, SLOTS, NPP * PAGE, PAGE, lambda *_: one_chip)
     scoped, plain = _compiled_both_ways(
-        monkeypatch, lambda e: paged.page_view(e, dh), entry)
+        monkeypatch, lambda e: paged.page_view(e, KVP, DH), entry)
     assert "kv_page_view/" in scoped and "kv_page_view" not in plain
     assert _without_metadata(scoped) == _without_metadata(plain)
 
@@ -233,8 +307,10 @@ def _renamed(text):
 
 
 def test_decode_step_scopes_rename_only(one_chip, monkeypatch):
-    # A whole decode step over the 2-bit paged cache: the scopes may
-    # renumber instruction names, and change nothing else.
+    # A whole decode step over the 2-bit paged cache, with the paged
+    # attention kernel in it: the scopes may renumber instruction names,
+    # and change nothing else.
+    _compile_paged_kernel(monkeypatch)
     from repro.configs import get_smoke
     from repro.models import model as model_mod
     from repro.models.common import ShardLayout
@@ -255,4 +331,40 @@ def test_decode_step_scopes_rename_only(one_chip, monkeypatch):
     scoped, plain = _compiled_both_ways(
         monkeypatch, make_serve_step(cfg, layout), *args)
     assert "kv_page_view/" in scoped and "kv_page_view" not in plain
+    assert "tpu_custom_call" in scoped
     assert _renamed(scoped) == _renamed(plain)
+
+
+def test_packed_decode_step_builds_no_dense_view(one_chip, monkeypatch):
+    # The packed path attends through the kernel: no (B, npp * page,
+    # KVp, dh) view, and no per-bit widening of the plane words, is left
+    # in the compiled step; the oracle (bf16 pages) path still builds it.
+    _compile_paged_kernel(monkeypatch)
+    from repro.configs import get_smoke
+    from repro.models import model as model_mod
+    from repro.models.common import ShardLayout
+    from repro.models.kvcache import init_caches
+    from repro.serving.engine import make_serve_step
+
+    layout = ShardLayout(tp=1)
+    key = jax.random.PRNGKey(0)
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda a: _struct(a.shape, a.dtype, one_chip), t)
+    texts = {}
+    for kvd in ("tnn2", "tnn2-oracle"):
+        cfg = get_smoke("tinyllama-1.1b").with_(kv_cache_dtype=kvd)
+        params = on_chip(jax.eval_shape(
+            lambda k: model_mod.init_lm(k, cfg, layout), key))
+        caches = on_chip(jax.eval_shape(
+            lambda: init_caches(cfg, layout, 4, 128, page_size=16)))
+        args = (params, caches, _struct((4, 1), jnp.int32, one_chip),
+                _struct((4,), jnp.int32, one_chip), on_chip(key))
+        texts[kvd] = jax.jit(make_serve_step(cfg, layout)).lower(
+            *args).compile().as_text()
+    # the smoke model: 2 kv heads of 16; 4 slots of 8 pages of 16 tokens.
+    # (B, npp * page, KVp, dh) in any dtype: a dense view of the packed
+    # pages decodes through u32[4,8,16,2,16] (a u32 a bit) to f32[4,128,2,16]
+    view = r"\[4,(128|8,16),2,16\]"
+    assert re.search(view, texts["tnn2-oracle"])
+    assert not re.search(view, texts["tnn2"])
+    assert "tpu_custom_call" in texts["tnn2"]
