@@ -15,7 +15,7 @@ The ``--json`` artifact carries, per layer x mode, both timings, the
 ``fused_speedup`` ratio (what the CI perf gate tracks — ratios are
 machine-portable, absolute times are not) and the im2col A-operand HBM
 bytes of each path (``hbm_bytes``): the materialized f32 patch matrix vs
-the packed activation planes the fused xla kernel stages — the
+the int8 activation codes the fused xla kernel stages — the
 memory-traffic win, quantified.
 
 Low-bit modes are enumerated from the kernel registry.
@@ -112,8 +112,7 @@ def run(quick=False, backend: str = DEFAULT_BACKEND,
             # noisy ratio would flake the gate
             tm = _time(lambda: fm(x), reps=max(reps, 11))
             tf = _time(lambda: ff(x), reps=max(reps, 11))
-            hbm = im2col_hbm_bytes(x.shape, packed.geometry, 1, "SAME",
-                                   mode)
+            hbm = im2col_hbm_bytes(x.shape, packed.geometry, 1, "SAME")
             layer_res[m].update({
                 "packed_s": tm,            # legacy key: materializing path
                 "packed_materializing_s": tm,

@@ -3,10 +3,9 @@
 ``conv2d_packed`` historically materialized the full ``(B*OH*OW,
 kh*kw*Cin)`` im2col patch matrix in HBM before the fused GeMM — for a
 3x3 conv that is a ~9x blow-up of the activation traffic which the
-kernel then re-reads.  The kernels here fold patch extraction into the
-A-operand load path instead: they read the raw ``(B, H, W, Cin)``
-activations, quantize + bit-plane pack them, and gather *packed* patch
-words on the fly, so the float patch matrix never exists.
+kernel then re-reads.  The kernels here read the raw ``(B, H, W, Cin)``
+activations and quantize them in their own load path, so the float
+patch matrix never exists.
 
 The key observation making this bit-exact against the materializing
 oracle is that the activation quantizers are **per-tensor**: ``thr`` and
@@ -19,11 +18,12 @@ kernels and the materializing ``im2col + ops.qmm(act_stats=...)``
 oracle — consume the same jitted stats computation, so their quantize /
 pack semantics are identical bit for bit.
 
-Operand layout: activations pack along the *channel* axis, one word
-vector per pixel; weights arrive in the matching per-patch-position
-layout (``conv_weight_planes``: a no-op re-view when ``Cin % 32 == 0``,
-the pack-time positional payload of ``POS_PAYLOAD_KEYS`` otherwise,
-with an exact in-trace repack as the legacy-container fallback).
+Operand layout: the popcount kernels pack activations along the
+*channel* axis, one word vector per pixel; weights arrive in the
+matching per-patch-position layout (``conv_weight_planes``: a no-op
+re-view when ``Cin % 32 == 0``, the pack-time positional payload of
+``POS_PAYLOAD_KEYS`` otherwise, with an exact in-trace repack as the
+legacy-container fallback).
 Word-aligned pads are zero on both sides — (0,0) ternary codes and
 ``+1`` binary codes on both operands — so the popcount sum over the
 per-position layout equals the contiguous-k sum exactly and eq. (6)
@@ -36,10 +36,10 @@ Three backends, mirroring the GeMM kernels:
   tile, quantizes + packs it in VMEM, runs the chunked popcount
   reduction against the B tile and applies the eq. (2) epilogue
   in-kernel (float32 out, no HBM round-trip of the accumulator);
-* ``xla``   — quantize + pack the activations once (elementwise), patch-
-  gather the *packed* words with one strided slice per patch position,
-  then the k-chunked popcount ``lax.scan`` with the epilogue fused onto
-  the final carry;
+* ``xla``   — quantize the padded activations once to int8 ±1/0 codes,
+  decode the weight planes to int8 codes, and let XLA's integer
+  convolution sum them in int32 (the int8 MXU on a TPU), the epilogue
+  after it — the cell that compiles for a TPU;
 * ``dense`` — lives in ``kernels/dense_fused.py``: same program_id patch
   gather, but the weight bit planes unpack to ±1/0 bf16 tiles in VMEM
   and the reduction rides ``jnp.dot`` / the MXU (integer-exact f32
@@ -48,9 +48,9 @@ Three backends, mirroring the GeMM kernels:
 All entries register under ``(mode, backend, fused=True,
 layout="im2col_fused")``; ``ops.qconv`` / ``conv2d_packed`` dispatch
 here with no API change (the QTensor already carries the conv geometry
-as static aux).  Pallas/XLA entries declare a ``TuningSpace`` so the
+as static aux).  The Pallas entries declare a ``TuningSpace`` so the
 autotuner covers them (``repro.tune`` — conv plans key on an extra
-``geom`` tag).
+``geom`` tag); XLA tiles the ``xla`` cell's convolution itself.
 """
 
 from __future__ import annotations
@@ -68,7 +68,7 @@ from repro.kernels._matmul_common import (ceil_to, pad2d, resolve_interpret,
                                           scale_epilogue)
 from repro.kernels.modes import QuantMode
 from repro.tune import cache as tune_cache
-from repro.tune.space import CONV_PALLAS_SPACE, XLA_SPACE
+from repro.tune.space import CONV_PALLAS_SPACE
 
 # NOTE: repro.core (encoding/quantize) and repro.kernels.ops are imported
 # lazily inside functions — ops imports this module to trigger
@@ -128,26 +128,24 @@ def conv_problem_dims(x_shape, geometry, stride: int, padding: str):
     return b * oh * ow, cout, kh * kw * cin, geom_tag(kh, kw, stride, padding)
 
 
-def im2col_hbm_bytes(x_shape, geometry, stride: int, padding: str,
-                     mode: QuantMode = QuantMode.TNN) -> Dict[str, int]:
+def im2col_hbm_bytes(x_shape, geometry, stride: int,
+                     padding: str) -> Dict[str, int]:
     """HBM bytes of the im2col A operand, materializing vs fused — the
     memory-traffic win the fused kernels buy (benchmarks report this).
 
     * materialized: the float32 patch matrix (m, k) the oracle writes
       then re-reads;
-    * fused: the packed activation bit planes the xla kernel stages
-      (1 or 2 uint32 words per 32 channels per pixel; the pallas kernel
-      reads the raw activations directly and stages nothing at all).
+    * fused: the int8 codes of the padded input the xla kernel stages
+      (one byte per element; the pallas kernel reads the raw
+      activations directly and stages nothing at all).
     """
     b, h, w, _ = x_shape
     kh, kw, cin, cout = geometry
     oh, ow, ph, pw = conv_out_hw(h, w, kh, kw, stride, padding)
     m, k = b * oh * ow, kh * kw * cin
-    planes = 1 if mode == QuantMode.BNN else 2   # ternary acts: 2 planes
-    cw = -(-cin // 32)
     return {
         "materialized": m * k * 4,
-        "fused": b * (h + ph) * (w + pw) * cw * 4 * planes,
+        "fused": b * (h + ph) * (w + pw) * cin,
     }
 
 
@@ -202,20 +200,6 @@ def conv_act_stats(x: jnp.ndarray, mode: QuantMode, kh: int, kw: int,
 # ---------------------------------------------------------------------------
 # Operand packing in the kernels' per-patch-position layout
 # ---------------------------------------------------------------------------
-
-def _pack_activation_planes(xp: jnp.ndarray, mode: QuantMode,
-                            stats: Dict[str, jnp.ndarray]):
-    """Quantize the padded input elementwise (per-tensor stats commute
-    with gathering) and pack bit planes along the channel axis: each
-    pixel becomes ceil(C/32) uint32 words per plane."""
-    from repro.core import encoding
-
-    if mode == QuantMode.BNN:
-        return (encoding.pack_bits(xp < 0),)           # +1 -> 0, -1 -> 1
-    mask = jnp.abs(xp) > stats["thr"]
-    t = jnp.sign(xp) * mask
-    return (encoding.pack_bits(t > 0), encoding.pack_bits(t < 0))
-
 
 def _conv_weight_planes(b_planes, mode: QuantMode, geometry):
     """LEGACY fallback: re-derive the per-patch-position weight planes
@@ -276,7 +260,8 @@ def interpreted_only(interpret: bool | None, backend: str) -> bool:
     one block and gather patches with a 4-D gather that Mosaic does not
     lower ("Only 2D gather is supported"), so a compiled launch raises
     here — never a silent fallback — until they get a band-blocked A
-    operand (ROADMAP S8).  The ``xla`` conv cell compiles everywhere."""
+    operand (ROADMAP S8).  The ``xla`` conv cell (an XLA integer
+    convolution) compiles everywhere."""
     if resolve_interpret(interpret):
         return True
     raise NotImplementedError(
@@ -307,74 +292,63 @@ def gather_patch_tile(xv: jnp.ndarray, pid_m, *, block_m: int, m: int,
 
 def quantize_patch_values(patch: jnp.ndarray, mode: QuantMode,
                           thr) -> jnp.ndarray:
-    """Elementwise per-tensor quantization of a gathered patch tile to
-    its ±1/0 *values* (per-tensor stats commute with gathering) — what
-    the dense kernels feed the MXU; the popcount kernels bit-plane pack
-    the same comparisons.  ``thr`` is ignored for BNN."""
+    """Elementwise per-tensor quantization of a gathered patch tile (or
+    the whole padded input) to its ±1/0 *values* (per-tensor stats
+    commute with gathering) — what the dense kernels and the ``xla``
+    cell feed the MXU; the popcount kernels bit-plane pack the same
+    comparisons.  ``thr`` is ignored for BNN."""
     if mode == QuantMode.BNN:
         return jnp.where(patch < 0, -1.0, 1.0)
     return jnp.sign(patch) * (jnp.abs(patch) > thr)
 
 
 # ---------------------------------------------------------------------------
-# XLA backend: quantize + pack once, patch-gather *packed* words, then
-# the k-chunked popcount scan with the epilogue on the final carry
+# XLA backend: ±1/0 int8 codes through XLA's integer convolution (MXU)
 # ---------------------------------------------------------------------------
 
-def _conv_xla_fused(mode: QuantMode, x, b_planes, geometry, stride, padding,
-                    stats, col_scale, bias, *, word_chunk: int):
-    """The production CPU/XLA form of the fused conv.
+def _filter_codes(b_planes, mode: QuantMode, geometry) -> jnp.ndarray:
+    """Decode the per-patch-position weight planes to ``(kh, kw, cin,
+    cout)`` int8 codes in {-1, 0, +1}.  Each position's run of
+    ceil(Cin/32) words unpacks to whole words; its pad channels are
+    sliced off, so the (dy, dx, c) order is the im2col column order."""
+    from repro.core import encoding
 
-    The materializing oracle im2cols the float activations (a ~kh*kw x
-    blow-up in f32) and then quantizes + packs that matrix.  Here the
-    order is inverted: quantize + pack happen ONCE on the (B, Hp, Wp,
-    Cin) input — per-tensor stats make quantization elementwise, so it
-    commutes with gathering — and patch extraction gathers the 32x
-    smaller *packed* words with one strided slice per (dy, dx) patch
-    position.  The popcount reduction is the same k-chunked ``lax.scan``
-    the GeMM kernels run, epilogue fused onto the final carry.
+    kh, kw, cin, cout = geometry
+    bits = b_planes[0].shape[-1] * encoding.WORD_BITS
+    if mode == QuantMode.TNN:
+        vals = encoding.unpack_ternary(b_planes[0], b_planes[1], bits,
+                                       dtype=jnp.int8)
+    else:
+        vals = encoding.unpack_binary(b_planes[0], bits, dtype=jnp.int8)
+    vals = vals.reshape(cout, kh, kw, -1)[..., :cin]
+    return jnp.transpose(vals, (1, 2, 3, 0))
+
+
+def _conv_xla_fused(mode: QuantMode, x, b_planes, geometry, stride, padding,
+                    stats, col_scale, bias):
+    """The compiled form of the fused conv, on every backend.
+
+    Per-tensor statistics make quantization elementwise, so the padded
+    input quantizes ONCE to int8 codes (padding first, in f32: a padded
+    zero is +1 for BNN, 0 for ternary, as in the im2col oracle), the
+    packed filters decode to int8 codes inside the trace, and XLA's
+    integer convolution sums the ±1/0 products in int32 on the MXU.
+    Those sums are the popcount core's integers (for BNN, the ±1 dot
+    ``k_valid - 2 * popcount``), and the epilogue keeps its multiply
+    order, so the output is bit-identical to the popcount kernels.
     """
     from repro.kernels import ops
 
-    kh, kw, cin, cout = geometry
-    k_valid = kh * kw * cin
-    xp, (oh, ow) = conv_spatial_pad(x.astype(jnp.float32), kh, kw,
-                                    stride, padding)
-    bsz = xp.shape[0]
-    a_full = _pack_activation_planes(xp, mode, stats)   # (B, Hp, Wp, cw) each
-    b_conv = tuple(b_planes)      # already per-patch-position layout
-    cw = a_full[0].shape[-1]
+    kh, kw, _, _ = geometry
+    xp, _ = conv_spatial_pad(x.astype(jnp.float32), kh, kw, stride, padding)
+    codes = quantize_patch_values(xp, mode, stats.get("thr"))
+    acc = jax.lax.conv_general_dilated(
+        codes.astype(jnp.int8), _filter_codes(b_planes, mode, geometry),
+        (stride, stride), "VALID",
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32)
     alpha = jnp.reshape(stats["scale"], (1, 1))
-    product = ops._PRODUCT_FNS[mode]
-
-    if mode == QuantMode.BNN:
-        def epi(pc):
-            return ops._scale_epilogue_f32(jnp.int32(k_valid) - 2 * pc,
-                                           alpha, col_scale, bias)
-    else:
-        def epi(acc):
-            return ops._scale_epilogue_f32(acc, alpha, col_scale, bias)
-
-    def gather(plane):
-        # One strided slice per patch position, concatenated in the
-        # (dy, dx) order of the im2col column layout — this is im2col on
-        # packed words (2 bits/element ternary, 1 bit binary), not on
-        # the float activations.
-        slabs = []
-        for dy in range(kh):
-            for dx in range(kw):
-                slabs.append(jax.lax.slice(
-                    plane, (0, dy, dx, 0),
-                    (bsz, dy + (oh - 1) * stride + 1,
-                     dx + (ow - 1) * stride + 1, cw),
-                    (1, stride, stride, 1)))          # (B, OH, OW, cw)
-        return jnp.concatenate(slabs, -1).reshape(bsz * oh * ow,
-                                                  kh * kw * cw)
-
-    a_pl = [gather(p) for p in a_full]
-    y = ops._chunked_bitwise_matmul(product, a_pl, list(b_conv),
-                                    word_chunk=word_chunk, epilogue=epi)
-    return y.reshape(bsz, oh, ow, cout)
+    return ops._scale_epilogue_f32(acc, alpha, col_scale, bias)
 
 
 # ---------------------------------------------------------------------------
@@ -519,12 +493,9 @@ def _register_conv_kernels():
     def make_xla(mode):
         def fn(x, b_planes, geometry, stride, padding, stats, col_scale,
                bias, *, interpret=None, tiles=None):
-            del interpret
-            t = _resolve_conv_tiles(mode, "xla", x.shape, geometry,
-                                    stride, padding, tiles)
+            del interpret, tiles      # XLA picks the conv's tiling
             return _conv_xla_fused(mode, x, b_planes, geometry, stride,
-                                   padding, stats, col_scale, bias,
-                                   word_chunk=t.word_chunk)
+                                   padding, stats, col_scale, bias)
         return fn
 
     for mode in (M.BNN, M.TNN, M.TBN):
@@ -537,10 +508,9 @@ def _register_conv_kernels():
         )(make_pallas(mode))
         registry.register(
             mode, "xla", fused=True, layout=registry.LAYOUT_IM2COL,
-            epilogue="scan-carry", compute="vpu-popcount",
-            tunable=XLA_SPACE,
-            description="pack-once activations; packed-word patch gather + "
-                        "k-chunked popcount scan",
+            epilogue="post-core", compute="mxu-int8",
+            description="quantize-once int8 codes; decoded int8 filters; "
+                        "lax conv with int32 sums",
         )(make_xla(mode))
 
 

@@ -33,10 +33,10 @@ arrays — 1 plane for binary operands, 2 (plus, minus) for ternary):
 ``tiles`` (a ``TileConfig``) overrides the kernel's blocking; ``None``
 resolves it from the autotuning plan cache at trace time (tuned plan on
 a hit, ``DEFAULT_TILES`` fallback otherwise).  Kernels with no tunable
-blocking (``tunable=None``, e.g. the materializing dense oracle, where
-XLA picks the tiling) accept and ignore the keyword; every FUSED entry
-— including the dense-backend MXU kernels of kernels/dense_fused.py —
-declares a ``TuningSpace``.
+blocking (``tunable=None``: the materializing dense oracle and the
+``xla`` conv cells, where XLA picks the tiling) accept and ignore the
+keyword; every other FUSED entry — including the dense-backend MXU
+kernels of kernels/dense_fused.py — declares a ``TuningSpace``.
 """
 
 from __future__ import annotations
@@ -63,13 +63,14 @@ class KernelSpec:
     fused: bool               # epilogue included in the kernel/trace
     fn: Callable
     epilogue: str             # "in-kernel" | "scan-carry" | "none"
-    compute: str              # "vpu-popcount" | "mxu-dense" | "mxu-xla" | ...
+    compute: str              # "vpu-popcount" | "mxu-dense" | "mxu-int8" | ...
     description: str = ""
     # Autotuning descriptor (repro.tune.space.TuningSpace) — the set of
     # (block_m, block_n, block_kw, word_chunk) candidates the tuner may
     # measure for this kernel.  None means the kernel has no tunable
-    # blocking (only the materializing dense oracle, where XLA picks the
-    # tiling — every fused entry declares a space).
+    # blocking (the materializing dense oracle and the xla conv cells,
+    # where XLA picks the tiling — every other fused entry declares a
+    # space).
     # Tunable kernels must accept a ``tiles=`` keyword (TileConfig).
     tunable: Optional[Any] = None
     layout: str = LAYOUT_GEMM  # "gemm" | "im2col_fused"

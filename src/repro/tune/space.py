@@ -200,9 +200,9 @@ INDEXED_SPACE = TuningSpace(kind="indexed",
 
 # Affine u8/u4 registry cells (ops.int8/int4_affine_matmul cores): the
 # kernels have no externally tunable blocking (XLA / the Pallas int
-# kernels pick their own tiling), but every fused registry entry
-# declares a space so the tuner sweep and the no-opt-out invariant stay
-# closed — one candidate, the default, which wins its own bake-off.
+# kernels pick their own tiling), but they declare a space so the tuner
+# sweep covers every fused GeMM cell — one candidate, the default, which
+# wins its own bake-off.
 AFFINE_SPACE = TuningSpace(kind="xla",
                            block_m=(128,), block_n=(128,),
                            block_kw=(256,), word_chunk=(8,))

@@ -6,10 +6,12 @@ and gather packed patch words on the fly; the oracle materializes the
 float patch matrix first.  Both consume the same per-tensor activation
 statistics (``conv_fused.conv_act_stats``), so their outputs must be
 **bit-identical** — asserted with array_equal, not allclose — for every
-mode x backend x stride/padding/odd-geometry case.  Plus: dispatch
-(conv2d_packed auto-selects the fused kernel), the retrace guard (one
-trace per conv geometry), autotuning plans for conv problems, and the
-engine/CLI integration points.
+mode x backend x stride/padding/odd-geometry case.  The ``xla`` cell
+convolves int8 ±1/0 codes with int32 sums and must also equal the
+``pallas`` popcount cell.  Plus: dispatch (conv2d_packed auto-selects
+the fused kernel), the retrace guard (one trace per conv geometry),
+autotuning plans for conv problems (the ``pallas`` cell; the ``xla``
+cell has no tuning space), and the engine/CLI integration points.
 """
 
 import os
@@ -69,8 +71,11 @@ def test_registry_has_im2col_fused_entries():
                                    layout=registry.LAYOUT_IM2COL)
             assert spec.layout == registry.LAYOUT_IM2COL
             assert spec.fused and spec.fn is not None
-            assert spec.tunable is not None      # ROADMAP: no silent opt-out
             assert ops.has_conv_kernel(mode, backend)
+            if backend == "xla":                 # XLA tiles its own conv
+                assert spec.compute == "mxu-int8" and spec.tunable is None
+            else:
+                assert spec.tunable is not None  # ROADMAP: no silent opt-out
     # the conv entries never shadow the GeMM entries
     for mode in MODES:
         for backend in BACKENDS:
@@ -112,6 +117,63 @@ def test_fused_bias_epilogue_bit_exact(mode, rng):
         got = conv.conv2d_packed(x, packed, backend=backend, fused=True)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
                                       err_msg=f"{mode} {backend} bias")
+
+
+# The xla cell against the pallas popcount cell and the oracle: Cin of 3
+# and 40 (not word multiples: the positional planes differ from the
+# contiguous payload) and 128, SAME borders (BNN's padded taps are +1),
+# VALID, stride 2, a bias.
+XLA_CASES = [
+    # (x shape,        filter shape,     stride, padding, bias)
+    ((2, 7, 6, 3),     (3, 3, 3, 8),     1, "SAME",  False),
+    ((1, 9, 8, 40),    (3, 3, 40, 16),   1, "VALID", True),
+    ((1, 8, 8, 128),   (3, 3, 128, 24),  2, "SAME",  False),
+    ((2, 6, 7, 40),    (3, 3, 40, 8),    2, "SAME",  True),
+]
+
+
+def _lax_zero_padded(x, packed, stride, padding):
+    """The planted fault: quantize the unpadded input and let lax pad
+    the int8 codes with 0, where BNN's padded taps must count +1."""
+    kh, kw, _, _ = packed.geometry
+    stats = conv_fused.conv_act_stats(x, packed.mode, kh, kw, stride, padding)
+    codes = conv_fused.quantize_patch_values(x, packed.mode,
+                                             stats.get("thr"))
+    acc = jax.lax.conv_general_dilated(
+        codes.astype(jnp.int8),
+        conv_fused._filter_codes(conv_fused.conv_weight_planes(packed),
+                                 packed.mode, packed.geometry),
+        (stride, stride), padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        preferred_element_type=jnp.int32)
+    cout = packed.geometry[3]
+    bias = None if packed.bias is None else ops._as_col_vec(packed.bias, cout)
+    return ops._scale_epilogue_f32(acc, stats["scale"].reshape(1, 1),
+                                   ops._as_col_vec(packed.scale, cout), bias)
+
+
+@pytest.mark.parametrize("mode", MODES)
+@pytest.mark.parametrize("case", XLA_CASES,
+                         ids=[f"cin{c[0][3]}s{c[2]}{c[3]}"
+                              f"{'bias' if c[4] else ''}" for c in XLA_CASES])
+def test_xla_int8_conv_matches_popcount_cells(mode, case):
+    xs, fs, stride, padding, with_bias = case
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(7), 3)
+    x, f = jax.random.normal(k1, xs), jax.random.normal(k2, fs)
+    bias = jax.random.normal(k3, (fs[-1],)) if with_bias else None
+    packed = conv.pack_conv_filters(f, mode, bias=bias)
+    want = np.asarray(conv.conv2d_packed(x, packed, stride=stride,
+                                         padding=padding, fused=False))
+    for backend in ("xla", "pallas"):
+        got = conv.conv2d_packed(x, packed, stride=stride, padding=padding,
+                                 backend=backend, fused=True)
+        np.testing.assert_array_equal(np.asarray(got), want,
+                                      err_msg=f"{mode} {backend} {case}")
+    if mode == QuantMode.BNN and padding == "SAME":
+        fault = np.asarray(_lax_zero_padded(x, packed, stride, padding))
+        assert fault.shape == want.shape
+        assert not np.array_equal(fault, want), \
+            "lax's zero padding went unseen at BNN's border taps"
 
 
 def test_conv2d_packed_dispatches_fused_by_default():
@@ -195,12 +257,12 @@ def test_conv_plan_roundtrip_and_key(tcache):
                              kernel_h=3, kernel_w=3)
     m, n, k, tag = prob.dims()
     assert (m, n, k, tag) == (2 * 8 * 8, 32, 144, "3x3s1same")
-    plan, measured = tuner.ensure_plan(QuantMode.TNN, "xla", conv=prob,
+    plan, measured = tuner.ensure_plan(QuantMode.TNN, "pallas", conv=prob,
                                        reps=1, warmup=1)
     assert measured and plan.layout == registry.LAYOUT_IM2COL
     assert plan.geom == "3x3s1same" and "im2col_fused" in plan.key
     # second call: pure cache hit; survives a JSON round-trip
-    plan2, measured2 = tuner.ensure_plan(QuantMode.TNN, "xla", conv=prob)
+    plan2, measured2 = tuner.ensure_plan(QuantMode.TNN, "pallas", conv=prob)
     assert not measured2 and plan2 == plan
     fresh = plan_cache.PlanCache(tcache.path).load()
     assert fresh.get(plan.key) == plan
@@ -215,11 +277,11 @@ def test_conv_dispatch_consults_plan_cache(tcache):
     from repro.kernels._matmul_common import DEFAULT_TILES, TileConfig
     tuned = TileConfig(word_chunk=2)
     tcache.put(plan_cache.Plan(
-        mode=QuantMode.TNN, backend="xla", fused=True,
+        mode=QuantMode.TNN, backend="pallas", fused=True,
         device_kind=plan_cache.device_kind(),
         m_bucket=plan_cache.bucket_m(m), n=n, k=k, tiles=tuned,
         layout=registry.LAYOUT_IM2COL, geom=tag))
-    spec = registry.lookup(QuantMode.TNN, "xla", fused=True,
+    spec = registry.lookup(QuantMode.TNN, "pallas", fused=True,
                            layout=registry.LAYOUT_IM2COL)
     x, b_pl, stats, col = tuner._make_conv_problem(QuantMode.TNN, prob, 0)
 
@@ -239,10 +301,10 @@ def test_conv_tuning_preserves_numerics(tcache, rng):
     f = jax.random.normal(k1, (3, 3, 16, 8))
     x = jax.random.normal(k2, (1, 6, 6, 16))
     packed = conv.pack_conv_filters(f, QuantMode.TBN)
-    y0 = np.asarray(conv.conv2d_packed(x, packed, backend="xla"))
+    y0 = np.asarray(conv.conv2d_packed(x, packed, backend="pallas"))
     prob = tuner.ConvProblem.from_input(x.shape, packed.geometry)
-    tuner.ensure_plan(QuantMode.TBN, "xla", conv=prob, reps=1, warmup=1)
-    y1 = np.asarray(conv.conv2d_packed(x, packed, backend="xla"))
+    tuner.ensure_plan(QuantMode.TBN, "pallas", conv=prob, reps=1, warmup=1)
+    y1 = np.asarray(conv.conv2d_packed(x, packed, backend="pallas"))
     np.testing.assert_array_equal(y0, y1)
 
 
@@ -252,7 +314,7 @@ def test_on_first_use_policy_tunes_conv_shapes(tcache, rng):
     f = jax.random.normal(k1, (3, 3, 8, 16))
     x = jax.random.normal(k2, (1, 5, 5, 8))
     packed = conv.pack_conv_filters(f, QuantMode.TNN)
-    conv.conv2d_packed(x, packed, backend="xla").block_until_ready()
+    conv.conv2d_packed(x, packed, backend="pallas").block_until_ready()
     probs = [p for p in tcache.plans().values()
              if p.layout == registry.LAYOUT_IM2COL]
     assert probs and all(p.source == "tuned" for p in probs)
@@ -271,12 +333,16 @@ def test_collect_problems_reports_conv_geometry(rng):
     assert (QuantMode.BNN, 36, 8, (3, 3, 4, 8)) in probs
 
 
-def test_engine_autotune_sweeps_conv_problems(tcache, rng):
+def test_engine_autotune_sweeps_conv_problems(tcache, rng, monkeypatch):
     """ServeConfig.tune_conv_inputs: an offline sweep must persist
     im2col_fused plans for every conv-packed QTensor in the params at
     the configured input extents (exercised through Engine._autotune's
-    own code path, with a minimal stand-in for the engine state)."""
+    own code path, with a minimal stand-in for the engine state, on the
+    tunable ``pallas`` conv cell)."""
+    from repro.kernels import modes
     from repro.serving.engine import Engine, ServeConfig
+
+    monkeypatch.setattr(modes, "DEFAULT_BACKEND", "pallas")
 
     params = {"conv": conv.pack_conv_filters(
         jax.random.normal(rng, (3, 3, 8, 16)), QuantMode.TNN)}
@@ -301,7 +367,7 @@ def test_engine_autotune_sweeps_conv_problems(tcache, rng):
 
 def test_cli_conv_sweep_second_run_byte_identical(tcache, capsys):
     argv = ["--shapes", "8x32x96", "--conv-shapes", "1x6x6x8x16x3",
-            "--modes", "tnn", "--backends", "xla",
+            "--modes", "tnn", "--backends", "pallas",
             "--reps", "1", "--warmup", "1", "--cache", tcache.path]
     assert tune_cli(argv) == 0
     out1 = capsys.readouterr().out
@@ -311,3 +377,26 @@ def test_cli_conv_sweep_second_run_byte_identical(tcache, capsys):
     out2 = capsys.readouterr().out
     assert "measured=0" in out2 and "cached=2" in out2
     assert open(tcache.path, "rb").read() == bytes1
+
+
+def test_untunable_xla_conv_cell_plans_without_measuring(tcache, monkeypatch):
+    """The ``xla`` conv cell declares no tuning space: ``plan_for`` and
+    ``ensure_plan`` still answer with a plan for it, and time nothing."""
+    def no_timing(*a, **k):
+        raise AssertionError("an untunable cell was measured")
+
+    monkeypatch.setattr(tuner, "measure", no_timing)
+    prob = tuner.ConvProblem(batch=1, height=6, width=6, cin=8, cout=16,
+                             kernel_h=3, kernel_w=3)
+    m, n, k, tag = prob.dims()
+    reports = {}
+    plan, _ = tuner.ensure_plan(QuantMode.TNN, "xla", conv=prob,
+                                reports=reports)
+    assert plan.backend == "xla" and plan.layout == registry.LAYOUT_IM2COL
+    assert plan.geom == tag and plan.source == "default"
+    assert all(r.get("untunable") and not r["candidates"]
+               for r in reports.values())
+    looked_up = plan_cache.plan_for(QuantMode.TNN, "xla", fused=True, m=m,
+                                    n=n, k=k, layout=registry.LAYOUT_IM2COL,
+                                    geom=tag)
+    assert looked_up.tiles == plan.tiles

@@ -204,6 +204,51 @@ def test_qconv_scope_is_metadata_only(one_chip, monkeypatch):
     assert _without_metadata(scoped) == _without_metadata(plain)
 
 
+def _cnn_lowbit_convs():
+    """(mode, batch, h, w, cin, cout) of the CNN cell's low-bit convs,
+    from its configuration through the benchmark's own layer list."""
+    import importlib.util
+    import json
+    from pathlib import Path
+
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    spec = importlib.util.spec_from_file_location(
+        "_bench_counts", bench / "benchkit" / "counts.py")
+    counts = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(counts)
+    cfg = json.loads((bench / "configs" / "binarynet-cifar10.json").read_text())
+    return [(QuantMode(L["mode"]), 1024, L["h"], L["w"], L["cin"], L["cout"])
+            for L in counts.cnn_layers(cfg)
+            if L["kind"] == "conv" and QuantMode(L["mode"]).is_lowbit]
+
+
+def _operand_types(text, names):
+    return [re.search(rf"%{re.escape(n)} = (\w+)\[", text).group(1)
+            for n in names]
+
+
+@pytest.mark.parametrize("conv", _cnn_lowbit_convs(),
+                         ids=lambda c: f"{c[0].value}-{c[2]}x{c[3]}x{c[4]}-{c[5]}")
+def test_cnn_qconv_is_an_int8_convolution_on_v5e(one_chip, conv):
+    # convs 2-6 of the CNN cell at batch 1024: one s8 x s8 -> s32
+    # convolution each, and nothing of the popcount scan left
+    from repro.core.conv import pack_conv_filters
+
+    mode, b, h, w_, cin, cout = conv
+    w = jax.ShapeDtypeStruct((3, 3, cin, cout), jnp.float32)
+    qt = jax.eval_shape(lambda w: pack_conv_filters(w, mode), w)
+    qt = jax.tree.map(lambda l: _struct(l.shape, l.dtype, one_chip), qt)
+    x = _struct((b, h, w_, cin), jnp.float32, one_chip)
+    text = jax.jit(lambda a, q: ops.qconv(a, q, backend="xla")).lower(
+        x, qt).compile().as_text()
+    convs = re.findall(r"= (\w+)\[[^\]]*\]\S* convolution\(%([\w.-]+), "
+                       r"%([\w.-]+)\)", text)
+    assert len(convs) == 1, convs
+    out, lhs, rhs = convs[0]
+    assert out == "s32" and _operand_types(text, (lhs, rhs)) == ["s8", "s8"]
+    assert "popcnt" not in text
+
+
 def _compile_paged_kernel(monkeypatch):
     """The paged attention kernel compiles for the chip here too,
     where the platform decision would interpret it (CPU backend)."""
